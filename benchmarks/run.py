@@ -35,6 +35,8 @@ import os
 import time
 from typing import Dict, List
 
+from repro import compile_cache
+
 
 def _parse_rows(csv_block: str) -> List[Dict[str, object]]:
     """CSV block emitted by benchmarks.common.Suite → row dicts."""
@@ -143,6 +145,7 @@ def main() -> None:
                          "JSON (+ .metrics.json) artifacts")
     args = ap.parse_args()
     f = args.fast
+    compile_cache.enable()
 
     from benchmarks import (
         bench_adaptive,

@@ -15,11 +15,13 @@ import numpy as np  # noqa: E402
 
 import jax  # noqa: E402
 
+from repro import compile_cache  # noqa: E402
 from repro.core import distributed as D  # noqa: E402
 from repro.data import generate_social_graph  # noqa: E402
 
 
 def main():
+    compile_cache.enable()
     print(f"devices: {len(jax.devices())}")
     store, meta = generate_social_graph(scale=0.3)
     print(f"social graph: {meta}")

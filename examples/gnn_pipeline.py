@@ -12,6 +12,7 @@ import time
 import jax
 import numpy as np
 
+from repro import compile_cache
 from repro.core.storage import QuadStore
 from repro.models.gnn.models import GNNConfig, GraphShape, init, loss as gnn_loss
 from repro.models.gnn.sampler import BARQSampler, CSRSampler
@@ -20,6 +21,7 @@ from repro.train.optimizer import OptimizerConfig, adamw_update, init_opt_state
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=150)
     ap.add_argument("--n-nodes", type=int, default=2000)

@@ -3,7 +3,10 @@
     PYTHONPATH=src python examples/quickstart.py
 """
 
+from repro import compile_cache
 from repro.core import Engine, EngineConfig, QuadStore
+
+compile_cache.enable()
 
 # 1. build a store (insertion API; bulk loading uses add_encoded)
 store = QuadStore()

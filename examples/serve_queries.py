@@ -13,6 +13,7 @@ import argparse
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core import EngineConfig
 from repro.data import (
     BSBM_EXPLORE_TEMPLATES,
@@ -40,6 +41,7 @@ def build_workload(meta, n_requests: int, seed: int = 0):
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--requests", type=int, default=200)
     ap.add_argument("--scale", type=float, default=0.15)

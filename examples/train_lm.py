@@ -10,10 +10,12 @@ import logging
 import shutil
 import tempfile
 
+from repro import compile_cache
 from repro.launch.train import run
 
 
 def main():
+    compile_cache.enable()
     logging.basicConfig(level=logging.INFO, format="%(asctime)s %(message)s")
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-8b")

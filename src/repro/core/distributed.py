@@ -21,10 +21,9 @@ import functools
 from typing import Tuple
 
 import jax
-
-from repro.compat import shard_map
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 _SENTINEL = jnp.iinfo(jnp.int32).max

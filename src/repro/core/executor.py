@@ -98,7 +98,8 @@ class EngineConfig:
     # sideways information passing (DESIGN.md §12): None = cost-gated,
     # "on" = push prefilters wherever sound, "off" = disabled
     sip: Optional[str] = None
-    # kernel backend for the bloom summaries (None = REPRO_KERNEL_BACKEND)
+    # kernel backend for the bloom summaries (None = the platform's data
+    # plane, kernels.ops.default_backend)
     sip_backend: Optional[str] = None
     # buffer pooling (DESIGN.md §2.3): recycle batch buffers through an
     # Engine-owned arena so steady-state execution is allocation-free and

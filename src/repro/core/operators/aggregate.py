@@ -20,7 +20,7 @@ tests/test_aggregate.py):
   * MIN/MAX/AVG over an empty (or all-unbound / all-non-numeric) group
     leave the output variable unbound instead of encoding NaN.
 
-Backend note: numpy is the default backend and the float64 oracle; the
+Backend note: numpy is the host data plane and the float64 oracle; the
 jnp/Pallas segmented scans accumulate in float32, so their SUM/AVG partials
 are exact only for f32-representable magnitudes (integer sums below 2^24 —
 the same caveat as the expression VM, DESIGN.md §9.5). COUNT(DISTINCT *)

@@ -3,9 +3,11 @@
 Every per-batch computation in the BARQ operators funnels through these
 functions. They have three interchangeable implementations:
 
-  * this module — numpy, the engine's default CPU backend and the oracle;
+  * this module — numpy, the data plane on a host without a TPU, and the
+    oracle;
   * ``repro.kernels.ref`` — pure-jnp mirrors (jit-compiled);
-  * ``repro.kernels.*`` — Pallas TPU kernels (validated in interpret mode).
+  * ``repro.kernels.*`` — Pallas TPU kernels, the data plane on a TPU
+    (validated against this module in interpret mode).
 
 ``repro.kernels.ops`` dispatches between them. Operators never hand-roll
 per-row loops — that is the point of the paper.

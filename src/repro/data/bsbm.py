@@ -21,8 +21,10 @@ from repro.core.storage import QuadStore
 def generate_ecommerce_graph(
     scale: float = 0.1, seed: int = 7
 ) -> Tuple[QuadStore, Dict[str, int]]:
-    """scale 0.1 ~ 90K triples, 1.0 ~ 900K. Shape mirrors BSBM: ~20
-    products per type, ~18 features per product, ~8 offers, ~2 reviews."""
+    """4000 products per unit of scale, ~50 triples each: scale 0.1 gives
+    19,270 triples, 1.0 gives 199,186 and 10 gives 1,999,200. Shape
+    mirrors BSBM: ~20 products per type, ~18 features per product, ~8
+    offers, ~2 reviews."""
     rng = np.random.RandomState(seed)
     n_product = max(int(4000 * scale), 100)
     n_type = max(n_product // 20, 5)

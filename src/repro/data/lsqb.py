@@ -30,7 +30,7 @@ def _powerlaw_targets(rng, n: int, count: int, alpha: float = 1.6) -> np.ndarray
 def generate_social_graph(
     scale: float = 0.1, seed: int = 42
 ) -> Tuple[QuadStore, Dict[str, int]]:
-    """scale 0.1 ~ 60K triples; 0.3 ~ 200K; 1.0 ~ 700K (laptop-sized
+    """scale 0.1 gives 4,425 triples and 1.0 gives 45,373 (laptop-sized
     LSQB analogue; the paper's SF 0.3 has 7.3M — same shape, smaller N)."""
     rng = np.random.RandomState(seed)
     n_person = max(int(3000 * scale), 50)

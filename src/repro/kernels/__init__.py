@@ -7,7 +7,12 @@
     frontier_dedup   — property-path BFS delta-frontier masks
     gather_emit      — fused join emission (gather + NULL-extend + keys)
     radix_partition  — distributed-exchange partitioning
+    hash_join        — hash-join probe over a radix-partitioned build
+    bloom_filter     — SIP prefilter build + probe
 
-``repro.kernels.ops`` dispatches numpy / jnp-ref / pallas-interpret
-backends; ``repro.kernels.ref`` holds the pure-jnp oracles.
+``repro.kernels.ops`` dispatches numpy / jnp-ref / pallas backends, the
+platform picking the default; ``repro.kernels.ref`` holds the pure-jnp
+oracles. Each kernel module exposes a jitted ``*_kernel`` device entry over
+tile-aligned inputs and a host ``*_pallas`` wrapper that pads to
+power-of-two buckets (``tiling``).
 """

@@ -28,10 +28,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from repro.kernels import tiling
+
 K_BLOCK = 1024  # build keys per grid step
 Q_BLOCK = 1024  # probe queries per grid step
 W_TILE = 1024  # filter words resident per grid step
-_PAD = jnp.iinfo(jnp.int32).min
+_PAD = np.iinfo(np.int32).min
 _MULT1 = np.uint32(0x9E3779B1)
 _MULT2 = np.uint32(0x85EBCA6B)
 
@@ -59,16 +61,21 @@ def _build_kernel(keys_ref, out_ref, *, n_words: int):
     planes = (
         (bits[:, None] >> jnp.arange(32, dtype=jnp.uint32)[None, :])
         & jnp.uint32(1)
-    ).astype(jnp.int32) * sel[:, None].astype(jnp.int32)
+    ).astype(jnp.int32) * sel.astype(jnp.int32)[:, None]
     onehot = (
         jax.lax.iota(jnp.int32, W_TILE)[:, None] == rel[None, :]
-    ).astype(jnp.int32)  # (W_TILE, K_BLOCK)
-    counts = jnp.dot(onehot, planes)  # (W_TILE, 32) keys setting each bit
-    weights = jnp.uint32(1) << jnp.arange(32, dtype=jnp.uint32)
-    tile_or = jnp.sum(
-        jnp.where(counts > 0, weights[None, :], jnp.uint32(0)),
-        axis=1, dtype=jnp.uint32,
+    )  # (W_TILE, K_BLOCK)
+    # (W_TILE, 32) keys setting each bit: 0/1 operands in bf16 on the MXU
+    # (it multiplies no int32), counts exact in the float32 accumulator
+    counts = jnp.dot(
+        jnp.where(onehot, 1.0, 0.0).astype(jnp.bfloat16),
+        planes.astype(jnp.float32).astype(jnp.bfloat16),
+        preferred_element_type=jnp.float32,
     )
+    # OR of distinct single-bit words == their int32 sum (no carries; bit
+    # 31 wraps to INT32_MIN): the TPU reduces no unsigned integers
+    weights = jnp.left_shift(jnp.int32(1), jax.lax.iota(jnp.int32, 32))
+    tile_or = jnp.sum(jnp.where(counts > 0, weights[None, :], 0), axis=1)
 
     @pl.when(j == 0)
     def _init():
@@ -80,31 +87,33 @@ def _build_kernel(keys_ref, out_ref, *, n_words: int):
 
 
 @functools.partial(jax.jit, static_argnames=("n_words", "interpret"))
-def bloom_build_pallas(
-    keys: jax.Array, n_words: int, interpret: bool = True
-) -> jax.Array:
-    """(n_words,) uint32 filter words — see vecops.bloom_build."""
-    assert n_words & (n_words - 1) == 0, "n_words must be a power of two"
-    n = keys.shape[0]
-    k_pad = pl.cdiv(max(n, 1), K_BLOCK) * K_BLOCK
-    w_pad = pl.cdiv(n_words, W_TILE) * W_TILE
-    keys_p = (
-        jnp.full((k_pad,), _PAD, jnp.int32).at[:n].set(keys.astype(jnp.int32))
-    )
-    words = pl.pallas_call(
+def bloom_build_kernel(keys: jax.Array, *, n_words: int, interpret
+                       ) -> jax.Array:
+    """Device entry over K_BLOCK-aligned padded keys: (W,) int32 bit
+    patterns of the filter words, W = n_words rounded up to W_TILE."""
+    w_pad = tiling.bucket(n_words, W_TILE)
+    return pl.pallas_call(
         functools.partial(_build_kernel, n_words=n_words),
-        grid=(w_pad // W_TILE, k_pad // K_BLOCK),
+        grid=(w_pad // W_TILE, keys.shape[0] // K_BLOCK),
         in_specs=[pl.BlockSpec((K_BLOCK,), lambda i, j: (j,))],
         out_specs=pl.BlockSpec((W_TILE,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((w_pad,), jnp.uint32),
+        out_shape=jax.ShapeDtypeStruct((w_pad,), jnp.int32),
         interpret=interpret,
-    )(keys_p)
-    return words[:n_words]
+    )(keys)
+
+
+def bloom_build_pallas(keys, n_words: int, *, interpret) -> np.ndarray:
+    """(n_words,) uint32 filter words — see vecops.bloom_build."""
+    assert n_words & (n_words - 1) == 0, "n_words must be a power of two"
+    words = bloom_build_kernel(
+        tiling.pad(keys, K_BLOCK, _PAD), n_words=n_words, interpret=interpret
+    )
+    return np.asarray(words)[:n_words].view(np.uint32)
 
 
 def _probe_kernel(words_ref, q_ref, out_ref, *, n_words: int):
     j = pl.program_id(1)  # word tile
-    words = words_ref[...]  # (W_TILE,) uint32
+    words = words_ref[...]  # (W_TILE,) int32 bit patterns
     q = q_ref[...]  # (Q_BLOCK,)
     word, _ = _hash(q, n_words)
     rel = word - j * W_TILE
@@ -113,10 +122,7 @@ def _probe_kernel(words_ref, q_ref, out_ref, *, n_words: int):
     onehot = (
         jax.lax.iota(jnp.int32, W_TILE)[:, None] == rel[None, :]
     ) & sel[None, :]
-    vals = jnp.sum(
-        jnp.where(onehot, words[:, None], jnp.uint32(0)),
-        axis=0, dtype=jnp.uint32,
-    )
+    vals = jnp.sum(jnp.where(onehot, words[:, None], 0), axis=0)
 
     @pl.when(j == 0)
     def _init():
@@ -127,33 +133,36 @@ def _probe_kernel(words_ref, q_ref, out_ref, *, n_words: int):
         out_ref[...] = out_ref[...] + vals  # exactly one tile is nonzero
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def bloom_probe_pallas(
-    words: jax.Array, queries: jax.Array, interpret: bool = True
-) -> jax.Array:
-    """(C,) bool membership mask — see vecops.bloom_probe."""
-    n_words = int(words.shape[0])
-    c = queries.shape[0]
-    q_pad = pl.cdiv(max(c, 1), Q_BLOCK) * Q_BLOCK
-    w_pad = pl.cdiv(n_words, W_TILE) * W_TILE
-    q_p = (
-        jnp.full((q_pad,), _PAD, jnp.int32)
-        .at[:c]
-        .set(queries.astype(jnp.int32))
-    )
-    words_p = (
-        jnp.zeros((w_pad,), jnp.uint32).at[:n_words].set(words)
-    )
+@functools.partial(jax.jit, static_argnames=("n_words", "interpret"))
+def bloom_probe_kernel(words: jax.Array, queries: jax.Array, *, n_words: int,
+                       interpret) -> jax.Array:
+    """Device entry: W_TILE-aligned int32 word patterns and Q_BLOCK-aligned
+    queries → (Q,) bool membership."""
     gathered = pl.pallas_call(
         functools.partial(_probe_kernel, n_words=n_words),
-        grid=(q_pad // Q_BLOCK, w_pad // W_TILE),
+        grid=(queries.shape[0] // Q_BLOCK, words.shape[0] // W_TILE),
         in_specs=[
             pl.BlockSpec((W_TILE,), lambda i, j: (j,)),
             pl.BlockSpec((Q_BLOCK,), lambda i, j: (i,)),
         ],
         out_specs=pl.BlockSpec((Q_BLOCK,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((q_pad,), jnp.uint32),
+        out_shape=jax.ShapeDtypeStruct(queries.shape, jnp.int32),
         interpret=interpret,
-    )(words_p, q_p)
-    _, bits = _hash(q_p[:c], n_words)
-    return (gathered[:c] & bits) == bits
+    )(words, queries)
+    _, bits = _hash(queries, n_words)
+    bits = jax.lax.bitcast_convert_type(bits, jnp.int32)
+    return (gathered & bits) == bits
+
+
+def bloom_probe_pallas(words, queries, *, interpret) -> np.ndarray:
+    """(C,) bool membership mask — see vecops.bloom_probe."""
+    n_words = len(words)
+    c = len(queries)
+    words = np.asarray(words, np.uint32).view(np.int32)
+    mask = bloom_probe_kernel(
+        tiling.pad(words, W_TILE, 0),
+        tiling.pad(queries, Q_BLOCK, _PAD),
+        n_words=n_words,
+        interpret=interpret,
+    )
+    return np.asarray(mask)[:c]

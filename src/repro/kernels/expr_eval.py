@@ -22,10 +22,12 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.core.exprs.bytecode import ExprProgram
 from repro.core.exprs.vm import _interp
+from repro.kernels import tiling
 
 # wide blocks: the register file is a handful of (BLOCK,) vectors, so VMEM
 # stays small even at 8k lanes, and fewer grid steps amortize dispatch
@@ -40,38 +42,41 @@ def _kernel(icols_ref, fcols_ref, val_ref, err_ref, *, prog: ExprProgram):
 
 
 @functools.partial(jax.jit, static_argnames=("prog", "interpret"))
-def expr_eval_pallas(
+def expr_eval_kernel(
     icols: jax.Array,
     fcols: jax.Array,
+    *,
     prog: ExprProgram,
-    interpret: bool = True,
+    interpret,
 ):
+    """Device entry over BLOCK-aligned padded columns: (value, error)."""
     ki, n = icols.shape
     kf = fcols.shape[0]
-    n_pad = pl.cdiv(max(n, 1), BLOCK) * BLOCK
-    # padding rows: NULL codes / NaN values — they evaluate to errors that
-    # the final slice drops
-    icols_p = jnp.full((ki, n_pad), -1, jnp.int32).at[:, :n].set(
-        icols.astype(jnp.int32)
-    )
-    fcols_p = jnp.full((kf, n_pad), jnp.nan, jnp.float32).at[:, :n].set(
-        fcols.astype(jnp.float32)
-    )
-    val, err = pl.pallas_call(
+    out = pl.BlockSpec((BLOCK,), lambda i: (i,))
+    return pl.pallas_call(
         functools.partial(_kernel, prog=prog),
-        grid=(n_pad // BLOCK,),
+        grid=(n // BLOCK,),
         in_specs=[
             pl.BlockSpec((ki, BLOCK), lambda i: (0, i)),
             pl.BlockSpec((kf, BLOCK), lambda i: (0, i)),
         ],
-        out_specs=[
-            pl.BlockSpec((BLOCK,), lambda i: (i,)),
-            pl.BlockSpec((BLOCK,), lambda i: (i,)),
-        ],
+        out_specs=[out, out],
         out_shape=[
-            jax.ShapeDtypeStruct((n_pad,), jnp.float32),
-            jax.ShapeDtypeStruct((n_pad,), jnp.bool_),
+            jax.ShapeDtypeStruct((n,), jnp.float32),
+            jax.ShapeDtypeStruct((n,), jnp.bool_),
         ],
         interpret=interpret,
-    )(icols_p, fcols_p)
-    return val[:n], err[:n]
+    )(icols, fcols)
+
+
+def expr_eval_pallas(icols, fcols, prog: ExprProgram, *, interpret):
+    n = icols.shape[1]
+    # padding rows: NULL codes / NaN values — they evaluate to errors that
+    # the final slice drops
+    val, err = expr_eval_kernel(
+        tiling.pad(icols, BLOCK, -1),
+        tiling.pad(fcols, BLOCK, np.nan, np.float32),
+        prog=prog,
+        interpret=interpret,
+    )
+    return np.asarray(val)[:n], np.asarray(err)[:n]

@@ -17,11 +17,14 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
-C_BLOCK = 512
+from repro.kernels import tiling
+
+C_BLOCK = 1024
 V_TILE = 2048
-_PAD = jnp.iinfo(jnp.int32).min  # visited padding: matches no candidate
+_PAD = np.iinfo(np.int32).min  # visited padding: matches no candidate
 
 
 def _kernel(vh_ref, vl_ref, ch_ref, cl_ref, ph_ref, pl_ref, out_ref):
@@ -49,43 +52,37 @@ def _kernel(vh_ref, vl_ref, ch_ref, cl_ref, ph_ref, pl_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def frontier_dedup_pallas(
-    cand_hi: jax.Array,
-    cand_lo: jax.Array,
-    vis_hi: jax.Array,
-    vis_lo: jax.Array,
-    interpret: bool = True,
-) -> jax.Array:
-    """(C,) bool mask — see vecops.frontier_dedup for the contract."""
-    c, v = cand_hi.shape[0], vis_hi.shape[0]
-    c_pad = pl.cdiv(max(c, 1), C_BLOCK) * C_BLOCK
-    v_pad = pl.cdiv(max(v, 1), V_TILE) * V_TILE
-
-    def pad_c(a, fill):
-        return jnp.full((c_pad,), fill, jnp.int32).at[:c].set(a.astype(jnp.int32))
-
-    ch = pad_c(cand_hi, _PAD)
-    cl = pad_c(cand_lo, _PAD)
-    # left-neighbor columns; the first candidate gets a sentinel neighbor
-    ph = jnp.full((c_pad,), _PAD, jnp.int32).at[1:c].set(cand_hi[: c - 1].astype(jnp.int32))
-    pl_ = jnp.full((c_pad,), _PAD, jnp.int32).at[1:c].set(cand_lo[: c - 1].astype(jnp.int32))
-    vh = jnp.full((v_pad,), _PAD, jnp.int32).at[:v].set(vis_hi.astype(jnp.int32))
-    vl = jnp.full((v_pad,), _PAD, jnp.int32).at[:v].set(vis_lo.astype(jnp.int32))
-
-    grid = (c_pad // C_BLOCK, v_pad // V_TILE)
-    counts = pl.pallas_call(
+def frontier_dedup_kernel(vh, vl, ch, cl, ph, pl_, *, interpret) -> jax.Array:
+    """Device entry: (C,) hit counts over tile-aligned padded columns."""
+    grid = (ch.shape[0] // C_BLOCK, vh.shape[0] // V_TILE)
+    vis = pl.BlockSpec((V_TILE,), lambda i, j: (j,))
+    cand = pl.BlockSpec((C_BLOCK,), lambda i, j: (i,))
+    return pl.pallas_call(
         _kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((V_TILE,), lambda i, j: (j,)),
-            pl.BlockSpec((V_TILE,), lambda i, j: (j,)),
-            pl.BlockSpec((C_BLOCK,), lambda i, j: (i,)),
-            pl.BlockSpec((C_BLOCK,), lambda i, j: (i,)),
-            pl.BlockSpec((C_BLOCK,), lambda i, j: (i,)),
-            pl.BlockSpec((C_BLOCK,), lambda i, j: (i,)),
-        ],
-        out_specs=pl.BlockSpec((C_BLOCK,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((c_pad,), jnp.int32),
+        in_specs=[vis, vis, cand, cand, cand, cand],
+        out_specs=cand,
+        out_shape=jax.ShapeDtypeStruct(ch.shape, jnp.int32),
         interpret=interpret,
     )(vh, vl, ch, cl, ph, pl_)
-    return counts[:c] == 0
+
+
+def frontier_dedup_pallas(cand_hi, cand_lo, vis_hi, vis_lo, *, interpret
+                          ) -> np.ndarray:
+    """(C,) bool mask — see vecops.frontier_dedup for the contract."""
+    c = len(cand_hi)
+    cand_hi = np.asarray(cand_hi, np.int32)
+    cand_lo = np.asarray(cand_lo, np.int32)
+    # left-neighbor columns; the first candidate gets a sentinel neighbor
+    ph = np.concatenate([[_PAD], cand_hi[:-1]]).astype(np.int32)
+    pl_ = np.concatenate([[_PAD], cand_lo[:-1]]).astype(np.int32)
+    counts = frontier_dedup_kernel(
+        tiling.pad(vis_hi, V_TILE, _PAD),
+        tiling.pad(vis_lo, V_TILE, _PAD),
+        tiling.pad(cand_hi, C_BLOCK, _PAD),
+        tiling.pad(cand_lo, C_BLOCK, _PAD),
+        tiling.pad(ph, C_BLOCK, _PAD),
+        tiling.pad(pl_, C_BLOCK, _PAD),
+        interpret=interpret,
+    )
+    return np.asarray(counts)[:c] == 0

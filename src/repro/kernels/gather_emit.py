@@ -16,8 +16,10 @@ the grid reconstructs the gather exactly. The secondary-key mask and the
 virtual-row NULL fill run in the same kernel on the final chunk, while the
 gathered tile is still in VMEM — that is the fusion.
 
-Grid: (n_source_chunks, n_output_blocks); output tiles are indexed by the
-output block only, so they stay resident across the chunk axis.
+Grid: (n_output_blocks, n_source_chunks). The chunk axis is the inner
+one, so each output tile stays resident in VMEM while every chunk streams
+past it: the TPU writes an output block back when its index changes and
+never reads it in again.
 
 Layout contract (enforced by the kernels.ops wrapper): the *emitted* rows
 of each source come first and the rows referenced by the k-th equality
@@ -31,17 +33,20 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
-N_TILE = 512  # source rows streamed per chunk
-BLOCK = 256  # output slots per grid step
+from repro.kernels import tiling
+
+N_TILE = 1024  # source rows streamed per chunk
+BLOCK = 1024  # output slots per grid step
 
 _NULL = -1
 
 
 def _kernel(lsrc_ref, rsrc_ref, li_ref, ri_ref, lout_ref, rout_ref, mask_ref,
             *, n_pairs: int, n_chunks: int):
-    nc = pl.program_id(0)
+    nc = pl.program_id(1)
     n0 = nc * N_TILE
     li = li_ref[...]  # (BLOCK,)
     ri = ri_ref[...]
@@ -85,49 +90,53 @@ def _kernel(lsrc_ref, rsrc_ref, li_ref, ri_ref, lout_ref, rout_ref, mask_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("n_pairs", "interpret"))
-def gather_emit_pallas(
-    lsrc: jax.Array,  # (KL, NL) int32: emit rows first, pair-left rows at tail
-    rsrc: jax.Array,  # (KR, NR) int32: emit rows first, pair-right rows at tail
+def gather_emit_kernel(
+    lsrc: jax.Array,  # (KL, N) int32: emit rows first, pair-left rows at tail
+    rsrc: jax.Array,  # (KR, N) int32: emit rows first, pair-right rows at tail
     li: jax.Array,  # (C,) int32
     ri: jax.Array,  # (C,) int32; -1 = virtual NULL right row
+    *,
     n_pairs: int,
-    interpret: bool = True,
+    interpret,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Returns (lout (KL, C), rout (KR, C), mask (C,) int32)."""
-    kl, nl = lsrc.shape
-    kr, nr = rsrc.shape
+    """Device entry over tile-aligned padded inputs (N a multiple of
+    N_TILE, C of BLOCK): (lout (KL, C), rout (KR, C), mask (C,) int32)."""
+    kl, n = lsrc.shape
+    kr = rsrc.shape[0]
     c = li.shape[0]
-    n = max(nl, nr, 1)
-    n_chunks = pl.cdiv(n, N_TILE)
-    n_pad = n_chunks * N_TILE
-    c_blocks = pl.cdiv(c, BLOCK)
-    c_pad = c_blocks * BLOCK
-
-    lsrc = jnp.pad(lsrc.astype(jnp.int32), ((0, 0), (0, n_pad - nl)))
-    rsrc = jnp.pad(rsrc.astype(jnp.int32), ((0, 0), (0, n_pad - nr)))
-    # pad li with 0 (a real row; the padded output slots are sliced off) and
-    # ri with -1 (virtual, selects nothing)
-    li = jnp.pad(li.astype(jnp.int32), (0, c_pad - c))
-    ri = jnp.pad(ri.astype(jnp.int32), (0, c_pad - c), constant_values=_NULL)
-
-    grid = (n_chunks, c_blocks)
-    src_l = pl.BlockSpec((kl, N_TILE), lambda nc, cb: (0, nc))
-    src_r = pl.BlockSpec((kr, N_TILE), lambda nc, cb: (0, nc))
-    idx = pl.BlockSpec((BLOCK,), lambda nc, cb: (cb,))
-    out_l = pl.BlockSpec((kl, BLOCK), lambda nc, cb: (0, cb))
-    out_r = pl.BlockSpec((kr, BLOCK), lambda nc, cb: (0, cb))
-    out_m = pl.BlockSpec((BLOCK,), lambda nc, cb: (cb,))
-
-    lout, rout, mask = pl.pallas_call(
+    n_chunks = n // N_TILE
+    grid = (c // BLOCK, n_chunks)
+    src_l = pl.BlockSpec((kl, N_TILE), lambda cb, nc: (0, nc))
+    src_r = pl.BlockSpec((kr, N_TILE), lambda cb, nc: (0, nc))
+    idx = pl.BlockSpec((BLOCK,), lambda cb, nc: (cb,))
+    out_l = pl.BlockSpec((kl, BLOCK), lambda cb, nc: (0, cb))
+    out_r = pl.BlockSpec((kr, BLOCK), lambda cb, nc: (0, cb))
+    return pl.pallas_call(
         functools.partial(_kernel, n_pairs=n_pairs, n_chunks=n_chunks),
         grid=grid,
         in_specs=[src_l, src_r, idx, idx],
-        out_specs=[out_l, out_r, out_m],
+        out_specs=[out_l, out_r, idx],
         out_shape=[
-            jax.ShapeDtypeStruct((kl, c_pad), jnp.int32),
-            jax.ShapeDtypeStruct((kr, c_pad), jnp.int32),
-            jax.ShapeDtypeStruct((c_pad,), jnp.int32),
+            jax.ShapeDtypeStruct((kl, c), jnp.int32),
+            jax.ShapeDtypeStruct((kr, c), jnp.int32),
+            jax.ShapeDtypeStruct((c,), jnp.int32),
         ],
         interpret=interpret,
     )(lsrc, rsrc, li, ri)
-    return lout[:, :c], rout[:, :c], mask[:c]
+
+
+def gather_emit_pallas(lsrc, rsrc, li, ri, n_pairs: int, *, interpret
+                       ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """Returns (lout (KL, C), rout (KR, C), mask (C,) int32)."""
+    c = len(li)
+    n = max(lsrc.shape[1], rsrc.shape[1], 1)
+    lsrc = tiling.pad(lsrc, tiling.bucket(n, N_TILE), 0)
+    rsrc = tiling.pad(rsrc, tiling.bucket(n, N_TILE), 0)
+    # pad li with 0 (a real row; the padded output slots are sliced off) and
+    # ri with -1 (virtual, selects nothing)
+    lout, rout, mask = gather_emit_kernel(
+        lsrc, rsrc, tiling.pad(li, BLOCK, 0), tiling.pad(ri, BLOCK, _NULL),
+        n_pairs=n_pairs, interpret=interpret,
+    )
+    return (np.asarray(lout)[:, :c], np.asarray(rout)[:, :c],
+            np.asarray(mask)[:c])

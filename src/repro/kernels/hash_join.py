@@ -16,10 +16,12 @@ output block — the same tiled select-accumulate idiom as gather_emit and
 frontier_dedup. Keys are int32 (hi, lo) pairs compared lexicographically
 (hi >= 0, see vecops §11 header); no int64 anywhere, x64 stays off.
 
-Grid: (n_build_tiles, n_probe_blocks); outputs are indexed by the probe
-block only, so they stay resident across the build-tile axis. Build
-padding rows carry pid = INT32_MAX, which orders above every real
-(pid < n_parts) probe and therefore contributes zero to both counts.
+Grid: (n_probe_blocks, n_build_tiles). The build-tile axis is the inner
+one, so each output block stays resident in VMEM while every build tile
+streams past it: the TPU writes an output block back when its index
+changes and never reads it in again. Build padding rows carry
+pid = INT32_MAX, which orders above every real (pid < n_parts) probe and
+therefore contributes zero to both counts.
 """
 
 from __future__ import annotations
@@ -32,15 +34,17 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from repro.kernels import tiling
+
 N_TILE = 2048  # build rows streamed per chunk
-BLOCK = 512  # probe keys per grid step
+BLOCK = 1024  # probe keys per grid step
 
 _PAD_PID = np.int32(np.iinfo(np.int32).max)
 
 
 def _kernel(bpid_ref, bhi_ref, blo_ref, qpid_ref, qhi_ref, qlo_ref,
             lo_ref, hi_ref):
-    nc = pl.program_id(0)
+    nc = pl.program_id(1)
     bp, bh, bl = bpid_ref[...], bhi_ref[...], blo_ref[...]  # (N_TILE,)
     qp, qh, ql = qpid_ref[...], qhi_ref[...], qlo_ref[...]  # (BLOCK,)
 
@@ -67,45 +71,48 @@ def _kernel(bpid_ref, bhi_ref, blo_ref, qpid_ref, qhi_ref, qlo_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def hash_probe_pallas(
+def hash_probe_kernel(
     bpid: jax.Array,  # (N,) int32 build partition ids, partition-grouped
     bhi: jax.Array,  # (N,) int32 build key hi (>= 0), sorted within pid
     blo: jax.Array,  # (N,) int32 build key lo
     qpid: jax.Array,  # (C,) int32 probe partition ids
     qhi: jax.Array,  # (C,) int32 probe key hi
     qlo: jax.Array,  # (C,) int32 probe key lo
-    interpret: bool = True,
+    *,
+    interpret,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Returns (lo, hi) int32 run boundaries per probe key."""
-    n = bpid.shape[0]
+    """Device entry: (lo, hi) run boundaries over tile-aligned padded
+    inputs (N a multiple of N_TILE, C of BLOCK)."""
     c = qpid.shape[0]
-    n_chunks = pl.cdiv(max(n, 1), N_TILE)
-    n_pad = n_chunks * N_TILE
-    c_blocks = pl.cdiv(max(c, 1), BLOCK)
-    c_pad = c_blocks * BLOCK
-
-    bpid = jnp.pad(bpid.astype(jnp.int32), (0, n_pad - n),
-                   constant_values=_PAD_PID)
-    bhi = jnp.pad(bhi.astype(jnp.int32), (0, n_pad - n))
-    blo = jnp.pad(blo.astype(jnp.int32), (0, n_pad - n))
-    qpid = jnp.pad(qpid.astype(jnp.int32), (0, c_pad - c))
-    qhi = jnp.pad(qhi.astype(jnp.int32), (0, c_pad - c))
-    qlo = jnp.pad(qlo.astype(jnp.int32), (0, c_pad - c))
-
-    grid = (n_chunks, c_blocks)
-    src = pl.BlockSpec((N_TILE,), lambda nc, cb: (nc,))
-    qry = pl.BlockSpec((BLOCK,), lambda nc, cb: (cb,))
-    out = pl.BlockSpec((BLOCK,), lambda nc, cb: (cb,))
-
-    lo, hi = pl.pallas_call(
+    grid = (c // BLOCK, bpid.shape[0] // N_TILE)
+    src = pl.BlockSpec((N_TILE,), lambda cb, nc: (nc,))
+    qry = pl.BlockSpec((BLOCK,), lambda cb, nc: (cb,))
+    out = pl.BlockSpec((BLOCK,), lambda cb, nc: (cb,))
+    return pl.pallas_call(
         _kernel,
         grid=grid,
         in_specs=[src, src, src, qry, qry, qry],
         out_specs=[out, out],
         out_shape=[
-            jax.ShapeDtypeStruct((c_pad,), jnp.int32),
-            jax.ShapeDtypeStruct((c_pad,), jnp.int32),
+            jax.ShapeDtypeStruct((c,), jnp.int32),
+            jax.ShapeDtypeStruct((c,), jnp.int32),
         ],
         interpret=interpret,
     )(bpid, bhi, blo, qpid, qhi, qlo)
-    return lo[:c], hi[:c]
+
+
+def hash_probe_pallas(bpid, bhi, blo, qpid, qhi, qlo, *, interpret
+                      ) -> Tuple[np.ndarray, np.ndarray]:
+    """Returns (lo, hi) int32 run boundaries per probe key. Build padding
+    rows carry pid = INT32_MAX and count for no probe."""
+    c = len(qpid)
+    lo, hi = hash_probe_kernel(
+        tiling.pad(bpid, N_TILE, _PAD_PID),
+        tiling.pad(bhi, N_TILE, 0),
+        tiling.pad(blo, N_TILE, 0),
+        tiling.pad(qpid, BLOCK, 0),
+        tiling.pad(qhi, BLOCK, 0),
+        tiling.pad(qlo, BLOCK, 0),
+        interpret=interpret,
+    )
+    return np.asarray(lo)[:c], np.asarray(hi)[:c]

@@ -23,9 +23,12 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
-BLOCK = 512  # output slots per grid step
+from repro.kernels import tiling
+
+BLOCK = 1024  # output slots per grid step
 G_MAX = 2048  # max groups per kernel invocation (VMEM: G_MAX*BLOCK*4B tiles)
 
 
@@ -60,41 +63,64 @@ def _kernel(cum_hi_ref, cum_lo_ref, lstarts_ref, rstarts_ref, rlens_ref,
     ri_ref[...] = jnp.where(valid, ri, -1)
 
 
-@functools.partial(jax.jit, static_argnames=("count", "interpret"))
-def join_expand_pallas(
-    lstarts: jax.Array,
-    llens: jax.Array,  # unused by the kernel (cum encodes the products)
-    rstarts: jax.Array,
-    rlens: jax.Array,
-    cum: jax.Array,  # (G+1,) int32 cumulative output offsets
-    base,
-    count: int,
-    interpret: bool = True,
+@functools.partial(jax.jit, static_argnames=("n_out", "interpret"))
+def join_expand_kernel(
+    cum_hi: jax.Array,  # (G,) int32 end offset of each group's output
+    cum_lo: jax.Array,  # (G,) int32 start offset of each group's output
+    lstarts: jax.Array,  # (G,) int32
+    rstarts: jax.Array,  # (G,) int32
+    rlens: jax.Array,  # (G,) int32
+    base: jax.Array,  # (1,) int32 first output slot
+    total: jax.Array,  # (1,) int32 total output slots of all groups
+    *,
+    n_out: int,  # slots to emit, a multiple of BLOCK
+    interpret,
 ) -> Tuple[jax.Array, jax.Array]:
-    del llens
+    """Device entry: (li, ri) for output slots [base, base + n_out)."""
     g = lstarts.shape[0]
-    assert g <= G_MAX, f"split probes beyond {G_MAX} groups in the wrapper"
-    n_blocks = pl.cdiv(count, BLOCK)
-    padded = n_blocks * BLOCK
-
-    cum = cum.astype(jnp.int32)
-    total = cum[-1:]
-    cum_hi, cum_lo = cum[1:], cum[:-1]
-    base_arr = jnp.asarray([base], dtype=jnp.int32)
-
-    grid = (n_blocks,)
     full = pl.BlockSpec((g,), lambda i: (0,))
     scalar = pl.BlockSpec((1,), lambda i: (0,))
     out = pl.BlockSpec((BLOCK,), lambda i: (i,))
-    li, ri = pl.pallas_call(
+    return pl.pallas_call(
         _kernel,
-        grid=grid,
+        grid=(n_out // BLOCK,),
         in_specs=[full, full, full, full, full, scalar, scalar],
         out_specs=[out, out],
         out_shape=[
-            jax.ShapeDtypeStruct((padded,), jnp.int32),
-            jax.ShapeDtypeStruct((padded,), jnp.int32),
+            jax.ShapeDtypeStruct((n_out,), jnp.int32),
+            jax.ShapeDtypeStruct((n_out,), jnp.int32),
         ],
         interpret=interpret,
-    )(cum_hi, cum_lo, lstarts, rstarts, rlens, base_arr, total)
-    return li[:count], ri[:count]
+    )(cum_hi, cum_lo, lstarts, rstarts, rlens, base, total)
+
+
+def join_expand_pallas(
+    lstarts,
+    llens,  # unused by the kernel (cum encodes the products)
+    rstarts,
+    rlens,
+    cum,  # (G+1,) cumulative output offsets
+    base: int,
+    count: int,
+    *,
+    interpret,
+) -> Tuple[np.ndarray, np.ndarray]:
+    del llens
+    g = len(lstarts)
+    assert g <= G_MAX, f"split probes beyond {G_MAX} groups in the wrapper"
+    cum = np.asarray(cum, np.int32)
+    total = int(cum[-1])
+    # padded groups are empty and start at the total: no slot below the
+    # total ever selects one
+    li, ri = join_expand_kernel(
+        tiling.pad(cum[1:], BLOCK, total),
+        tiling.pad(cum[:-1], BLOCK, total),
+        tiling.pad(lstarts, BLOCK, 0),
+        tiling.pad(rstarts, BLOCK, 0),
+        tiling.pad(rlens, BLOCK, 1),
+        np.asarray([base], np.int32),
+        np.asarray([total], np.int32),
+        n_out=tiling.bucket(count, BLOCK),
+        interpret=interpret,
+    )
+    return np.asarray(li)[:count], np.asarray(ri)[:count]

@@ -1,20 +1,27 @@
 """Jit'd public wrappers + backend dispatch for the BARQ kernels.
 
 Backends:
-  numpy  — repro.core.vecops (CPU default, the engine's data plane here);
+  numpy  — repro.core.vecops (the data plane on any host without a TPU,
+           and the oracle);
   jax    — repro.kernels.ref jnp mirrors (jit; what XLA-TPU would run
            without custom kernels);
-  pallas — the Pallas TPU kernels, executed in interpret mode on CPU
-           (validated against both other backends in tests/test_kernels.py).
+  pallas — the Pallas TPU kernels (the data plane on a TPU), compiled for
+           the chip there and run in the TPU interpreter on any other
+           platform (validated against both other backends in
+           tests/test_kernels.py).
 
-Select globally with REPRO_KERNEL_BACKEND or per call with backend=...
+The platform picks the data plane (``default_backend``). A call may name
+its backend with backend=...; ``data_plane`` overrides the default for
+every dispatch in a block, so one process can run the same queries on
+both planes.
 """
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import functools
 import inspect
-import os
 import time
 from typing import Optional, Tuple
 
@@ -22,8 +29,48 @@ import numpy as np
 
 from repro.core import telemetry
 from repro.core import vecops
+from repro.kernels import tiling
 
-_DEFAULT = os.environ.get("REPRO_KERNEL_BACKEND", "numpy")
+_OVERRIDE: contextvars.ContextVar[Optional[str]] = contextvars.ContextVar(
+    "kernel_backend", default=None
+)
+
+
+@functools.lru_cache(maxsize=None)
+def _on_tpu() -> bool:
+    import jax
+
+    return jax.default_backend() == "tpu"
+
+
+def default_backend() -> str:
+    """The data plane of a dispatch that names no backend: the innermost
+    ``data_plane`` block's, else pallas on a TPU and numpy elsewhere."""
+    return _OVERRIDE.get() or ("pallas" if _on_tpu() else "numpy")
+
+
+@contextlib.contextmanager
+def data_plane(plane: str):
+    """Run every kernel dispatch that names no backend inside the block on
+    ``plane`` (scoped to the current thread or task)."""
+    token = _OVERRIDE.set(plane)
+    try:
+        yield
+    finally:
+        _OVERRIDE.reset(token)
+
+
+@functools.lru_cache(maxsize=None)
+def _interpret():
+    """Pallas kernels compile for the chip on a TPU. Anywhere else they run
+    in the TPU interpreter, which keeps the chip's block semantics: it
+    refuses, as the chip would miscompute, an output block that the grid
+    leaves and comes back to."""
+    if _on_tpu():
+        return False
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.InterpretParams()
 
 # Process-wide dispatch ledger: every public wrapper below counts one entry
 # per call under its kernel name. Observability for tests and benchmarks —
@@ -53,7 +100,7 @@ def reset_dispatch_counts() -> None:
 
 
 def _backend(override: Optional[str]) -> str:
-    return override or _DEFAULT
+    return override or default_backend()
 
 
 def _ledgered(fn):
@@ -72,7 +119,7 @@ def _ledgered(fn):
         be = kwargs.get("backend")
         if be is None and len(args) > bidx:
             be = args[bidx]
-        be = be or _DEFAULT
+        be = be or default_backend()
         t0 = time.perf_counter()
         try:
             return fn(*args, **kwargs)
@@ -102,10 +149,10 @@ def join_expand(
         from repro.kernels.join_expand import G_MAX, join_expand_pallas
 
         if len(lstarts) <= G_MAX:
-            li, ri = join_expand_pallas(
-                lstarts, llens, rstarts, rlens, cum, base, count
+            return join_expand_pallas(
+                lstarts, llens, rstarts, rlens, cum, base, count,
+                interpret=_interpret(),
             )
-            return np.asarray(li), np.asarray(ri)
         # split oversized probes into group chunks
         lis, ris = [], []
         emitted = 0
@@ -123,9 +170,10 @@ def join_expand(
                 (chunk_cum - chunk_cum[0]).astype(np.int32),
                 base + emitted - int(chunk_cum[0]),
                 take,
+                interpret=_interpret(),
             )
-            lis.append(np.asarray(li))
-            ris.append(np.asarray(ri))
+            lis.append(li)
+            ris.append(ri)
             emitted += take
             g0 = g1
         return np.concatenate(lis), np.concatenate(ris)
@@ -184,10 +232,10 @@ def gather_emit(
         rrows = [max(r, 0) for r in rsel] + [rp for _, rp in pairs]
         lsrc = lcols[lrows] if lrows else np.zeros((1, max(lcols.shape[1], 1)), np.int32)
         rsrc = rcols_n[rrows] if rrows else np.zeros((1, rcols_n.shape[1]), np.int32)
-        lout, rout, maski = gather_emit_pallas(lsrc, rsrc, li_n, ri_n, len(pairs))
-        lout, rout = np.asarray(lout), np.asarray(rout)
+        lout, rout, maski = gather_emit_pallas(lsrc, rsrc, li_n, ri_n, len(pairs),
+                                               interpret=_interpret())
         block = np.concatenate([lout[: len(lsel)], rout[: len(rsel)]], axis=0)
-        mask = np.asarray(maski).astype(bool)
+        mask = maski.astype(bool)
     else:
         raise ValueError(be)
 
@@ -221,7 +269,7 @@ def sorted_search(keys, queries, side: str = "left", backend: Optional[str] = No
     if be == "pallas":
         from repro.kernels.sorted_search import sorted_search_pallas
 
-        return np.asarray(sorted_search_pallas(keys, queries, side))
+        return sorted_search_pallas(keys, queries, side, interpret=_interpret())
     raise ValueError(be)
 
 
@@ -250,7 +298,8 @@ def frontier_dedup(
     if be == "pallas":
         from repro.kernels.frontier_dedup import frontier_dedup_pallas
 
-        return np.asarray(frontier_dedup_pallas(cand_hi, cand_lo, vis_hi, vis_lo))
+        return frontier_dedup_pallas(cand_hi, cand_lo, vis_hi, vis_lo,
+                                     interpret=_interpret())
     raise ValueError(be)
 
 
@@ -285,7 +334,7 @@ def segment_reduce(keys, values, func: str, backend: Optional[str] = None,
     elif be == "pallas":
         from repro.kernels.segment_reduce import segment_scan_pallas
 
-        scan = np.asarray(segment_scan_pallas(keys, vals, op))
+        scan = segment_scan_pallas(keys, vals, op, interpret=_interpret())
     else:
         raise ValueError(be)
     run_end = np.empty(n, dtype=bool)
@@ -320,8 +369,7 @@ def expr_eval(prog, icols, fcols, backend: Optional[str] = None):
     if be == "pallas":
         from repro.kernels.expr_eval import expr_eval_pallas
 
-        val, err = expr_eval_pallas(icols, fcols, prog)
-        return np.asarray(val), np.asarray(err)
+        return expr_eval_pallas(icols, fcols, prog, interpret=_interpret())
     raise ValueError(be)
 
 
@@ -342,8 +390,7 @@ def radix_partition(keys, n_parts: int, backend: Optional[str] = None):
     if be == "pallas":
         from repro.kernels.radix_partition import radix_partition_pallas
 
-        pid, hist = radix_partition_pallas(keys, n_parts)
-        return np.asarray(pid), np.asarray(hist)
+        return radix_partition_pallas(keys, n_parts, interpret=_interpret())
     raise ValueError(be)
 
 
@@ -382,7 +429,13 @@ def hash_build(
             if key_hi is None
             else np.asarray(key_hi, np.int32)
         )
-        order = np.asarray(ref.hash_build_order(pid, hi, key_lo))
+        # bucketed like the kernels; padding rows sort after every real one
+        n = len(key_lo)
+        order = np.asarray(ref.hash_build_order(
+            tiling.pad(pid, tiling.SORT_TILE, np.iinfo(np.int32).max),
+            tiling.pad(hi, tiling.SORT_TILE, 0),
+            tiling.pad(key_lo, tiling.SORT_TILE, 0),
+        ))[:n]
     else:
         raise ValueError(be)
     return order, part_starts
@@ -433,10 +486,8 @@ def hash_probe(
     if be == "pallas":
         from repro.kernels.hash_join import hash_probe_pallas
 
-        lo, hi = hash_probe_pallas(
-            np.asarray(spid, np.int32), shi, skey_lo, qpid, qhi, qkey_lo
-        )
-        return np.asarray(lo), np.asarray(hi)
+        return hash_probe_pallas(spid, shi, skey_lo, qpid, qhi, qkey_lo,
+                                 interpret=_interpret())
     raise ValueError(be)
 
 
@@ -464,7 +515,7 @@ def bloom_build(
     if be == "pallas":
         from repro.kernels.bloom_filter import bloom_build_pallas
 
-        return np.asarray(bloom_build_pallas(keys, n_words)), lo, hi
+        return bloom_build_pallas(keys, n_words, interpret=_interpret()), lo, hi
     raise ValueError(be)
 
 
@@ -484,5 +535,5 @@ def bloom_probe(words, queries, backend: Optional[str] = None) -> np.ndarray:
     if be == "pallas":
         from repro.kernels.bloom_filter import bloom_probe_pallas
 
-        return np.asarray(bloom_probe_pallas(words, queries))
+        return bloom_probe_pallas(words, queries, interpret=_interpret())
     raise ValueError(be)

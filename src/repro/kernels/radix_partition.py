@@ -17,8 +17,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from repro.kernels import tiling
+
 BLOCK = 2048
 _HASH_MULT = np.uint32(0x9E3779B1)
+_PAD = np.iinfo(np.int32).min  # padding: pid -1, counted in no partition
 
 
 def _kernel(keys_ref, pid_ref, hist_ref, *, n_parts: int):
@@ -26,7 +29,7 @@ def _kernel(keys_ref, pid_ref, hist_ref, *, n_parts: int):
     keys = keys_ref[...]
     h = (keys.astype(jnp.uint32) * _HASH_MULT) >> np.uint32(16)
     pid = (h & np.uint32(n_parts - 1)).astype(jnp.int32)
-    pid = jnp.where(keys == jnp.iinfo(jnp.int32).min, -1, pid)  # padding
+    pid = jnp.where(keys == _PAD, -1, pid)
     pid_ref[...] = pid
 
     parts = jax.lax.iota(jnp.int32, n_parts)
@@ -43,29 +46,31 @@ def _kernel(keys_ref, pid_ref, hist_ref, *, n_parts: int):
 
 
 @functools.partial(jax.jit, static_argnames=("n_parts", "interpret"))
-def radix_partition_pallas(
-    keys: jax.Array, n_parts: int, interpret: bool = True
+def radix_partition_kernel(
+    keys: jax.Array, *, n_parts: int, interpret
 ) -> Tuple[jax.Array, jax.Array]:
-    assert n_parts & (n_parts - 1) == 0, "n_parts must be a power of two"
-    n = keys.shape[0]
-    n_pad = pl.cdiv(max(n, 1), BLOCK) * BLOCK
-    keys_p = (
-        jnp.full((n_pad,), jnp.iinfo(jnp.int32).min, jnp.int32)
-        .at[:n]
-        .set(keys.astype(jnp.int32))
-    )
-    pid, hist = pl.pallas_call(
+    """Device entry over BLOCK-aligned padded keys: (pid, histogram)."""
+    return pl.pallas_call(
         functools.partial(_kernel, n_parts=n_parts),
-        grid=(n_pad // BLOCK,),
+        grid=(keys.shape[0] // BLOCK,),
         in_specs=[pl.BlockSpec((BLOCK,), lambda i: (i,))],
         out_specs=[
             pl.BlockSpec((BLOCK,), lambda i: (i,)),
             pl.BlockSpec((n_parts,), lambda i: (0,)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n_pad,), jnp.int32),
+            jax.ShapeDtypeStruct(keys.shape, jnp.int32),
             jax.ShapeDtypeStruct((n_parts,), jnp.int32),
         ],
         interpret=interpret,
-    )(keys_p)
-    return pid[:n], hist
+    )(keys)
+
+
+def radix_partition_pallas(keys, n_parts: int, *, interpret
+                           ) -> Tuple[np.ndarray, np.ndarray]:
+    assert n_parts & (n_parts - 1) == 0, "n_parts must be a power of two"
+    n = len(keys)
+    pid, hist = radix_partition_kernel(
+        tiling.pad(keys, BLOCK, _PAD), n_parts=n_parts, interpret=interpret
+    )
+    return np.asarray(pid)[:n], np.asarray(hist)

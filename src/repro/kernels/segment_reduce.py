@@ -4,7 +4,7 @@ the vectorized core of streaming aggregation (paper §3.3).
 out[i] = reduce(values over the maximal run of equal keys ending at i).
 Within a block: log-step doubling scan (for sorted keys, key[i]==key[i-d]
 implies the whole span is one run, so doubling is exact). Across blocks:
-the TPU grid is sequential, so a VMEM scratch carries (last_key, last_acc)
+the TPU grid is sequential, so VMEM scratch tiles carry (last_key, last_acc)
 — the batch-boundary carry merge the paper describes for associative
 aggregates ('aggregate within a batch and merge the results across
 batches').
@@ -16,11 +16,16 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-BLOCK = 1024
-_SENTINEL = jnp.iinfo(jnp.int32).min
+from repro.kernels import tiling
+
+LANES = 128
+ROWS = 8
+BLOCK = ROWS * LANES  # rows of the flat input per grid step, as (ROWS, LANES)
+_SENTINEL = np.iinfo(np.int32).min
 _IDENT = {"sum": 0.0, "count": 0.0, "min": float("inf"), "max": float("-inf")}
 _COMBINE = {
     "sum": jnp.add,
@@ -30,62 +35,91 @@ _COMBINE = {
 }
 
 
+def _shift(x, d: int, fill):
+    """y.flat[i] = x.flat[i - d] over the row-major flattening of a
+    (ROWS, LANES) tile, ``fill`` for i < d. Lane and sublane rotations
+    only: the TPU concatenates no vectors at unaligned offsets."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    flat = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0) * LANES + lane
+    if d % LANES == 0:
+        y = pltpu.roll(x, d // LANES, 0)
+    else:  # d < LANES: the first d lanes come from the previous row
+        r = pltpu.roll(x, d, 1)
+        y = jnp.where(lane >= d, r, pltpu.roll(r, 1, 0))
+    return jnp.where(flat >= d, y, fill)
+
+
+def _broadcast_last(x):
+    """x.flat[-1] over the whole (ROWS, LANES) tile, through one-axis
+    reductions and broadcasts: the TPU broadcasts no element across
+    sublanes and lanes at once."""
+    flat = (jax.lax.broadcasted_iota(jnp.int32, x.shape, 0) * LANES
+            + jax.lax.broadcasted_iota(jnp.int32, x.shape, 1))
+    only = jnp.where(flat == BLOCK - 1, x, jnp.zeros_like(x))
+    row = jnp.broadcast_to(jnp.sum(only, axis=1, keepdims=True), x.shape)
+    return jnp.broadcast_to(jnp.sum(row, axis=0, keepdims=True), x.shape)
+
+
 def _kernel(keys_ref, vals_ref, out_ref, carry_key, carry_val, *, op: str):
     b = pl.program_id(0)
-    keys = keys_ref[...]
-    out = vals_ref[...].astype(jnp.float32)
+    keys = keys_ref[...]  # (ROWS, LANES)
+    out = vals_ref[...]
     combine = _COMBINE[op]
     ident = jnp.float32(_IDENT[op])
 
     # in-block segmented doubling scan
     d = 1
     while d < BLOCK:
-        prev = jnp.concatenate([jnp.full((d,), ident, jnp.float32), out[:-d]])
-        prev_key = jnp.concatenate([jnp.full((d,), _SENTINEL, jnp.int32), keys[:-d]])
+        prev = _shift(out, d, ident)
+        prev_key = _shift(keys, d, _SENTINEL)
         out = jnp.where(keys == prev_key, combine(out, prev), out)
         d *= 2
 
+    # the carry is (last_key, last_acc) of the previous block, broadcast
+    # over a whole tile: the TPU stores no scalars to VMEM
     @pl.when(b == 0)
     def _init():
-        carry_key[0] = jnp.int32(_SENTINEL)
-        carry_val[0] = ident
+        carry_key[...] = jnp.full(keys.shape, _SENTINEL, jnp.int32)
+        carry_val[...] = jnp.full(out.shape, ident, jnp.float32)
 
     # merge the carried run (first run of this block only, keys are sorted)
-    ck, cv = carry_key[0], carry_val[0]
-    out = jnp.where(keys == ck, combine(out, cv), out)
+    out = jnp.where(keys == carry_key[...], combine(out, carry_val[...]), out)
 
     out_ref[...] = out
-    carry_key[0] = keys[BLOCK - 1]
-    carry_val[0] = out[BLOCK - 1]
+    carry_key[...] = _broadcast_last(keys)
+    carry_val[...] = _broadcast_last(out)
 
 
 @functools.partial(jax.jit, static_argnames=("op", "interpret"))
-def segment_scan_pallas(
-    keys: jax.Array, values: jax.Array, op: str = "sum", interpret: bool = True
+def segment_scan_kernel(
+    keys: jax.Array, values: jax.Array, *, op: str, interpret
 ) -> jax.Array:
-    n = keys.shape[0]
-    n_pad = pl.cdiv(max(n, 1), BLOCK) * BLOCK
-    keys_p = jnp.full((n_pad,), _SENTINEL + 1, jnp.int32).at[:n].set(
-        keys.astype(jnp.int32)
-    )
-    vals_p = (
-        jnp.full((n_pad,), _IDENT[op], jnp.float32)
-        .at[:n]
-        .set(values.astype(jnp.float32))
-    )
-    out = pl.pallas_call(
+    """Device entry over (R, LANES) int32 keys and float32 values, R a
+    multiple of ROWS: the scan runs over their row-major flattening."""
+    spec = pl.BlockSpec((ROWS, LANES), lambda i: (i, 0))
+    return pl.pallas_call(
         functools.partial(_kernel, op=op),
-        grid=(n_pad // BLOCK,),
-        in_specs=[
-            pl.BlockSpec((BLOCK,), lambda i: (i,)),
-            pl.BlockSpec((BLOCK,), lambda i: (i,)),
-        ],
-        out_specs=pl.BlockSpec((BLOCK,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n_pad,), jnp.float32),
+        grid=(keys.shape[0] // ROWS,),
+        in_specs=[spec, spec],
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct(keys.shape, jnp.float32),
         scratch_shapes=[
-            pltpu.VMEM((1,), jnp.int32),
-            pltpu.VMEM((1,), jnp.float32),
+            pltpu.VMEM((ROWS, LANES), jnp.int32),
+            pltpu.VMEM((ROWS, LANES), jnp.float32),
         ],
         interpret=interpret,
-    )(keys_p, vals_p)
-    return out[:n]
+    )(keys, values)
+
+
+def segment_scan_pallas(keys, values, op: str = "sum", *, interpret
+                        ) -> np.ndarray:
+    """(N,) float32 segmented inclusive scan; padding rows carry a key
+    below every real one and the identity value."""
+    n = len(keys)
+    out = segment_scan_kernel(
+        tiling.pad(keys, BLOCK, _SENTINEL + 1).reshape(-1, LANES),
+        tiling.pad(values, BLOCK, _IDENT[op], np.float32).reshape(-1, LANES),
+        op=op,
+        interpret=interpret,
+    )
+    return np.asarray(out).reshape(-1)[:n]

@@ -14,11 +14,14 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
-Q_BLOCK = 512
+from repro.kernels import tiling
+
+Q_BLOCK = 1024
 K_TILE = 2048
-_PAD_KEY = jnp.iinfo(jnp.int32).max  # never counted
+_PAD_KEY = np.iinfo(np.int32).max  # never counted
 
 
 def _kernel(keys_ref, q_ref, out_ref, *, left: bool):
@@ -37,26 +40,33 @@ def _kernel(keys_ref, q_ref, out_ref, *, left: bool):
         out_ref[...] = out_ref[...] + counts
 
 
-@functools.partial(jax.jit, static_argnames=("side", "interpret"))
-def sorted_search_pallas(
-    keys: jax.Array, queries: jax.Array, side: str = "left", interpret: bool = True
+@functools.partial(jax.jit, static_argnames=("left", "interpret"))
+def sorted_search_kernel(
+    keys: jax.Array, queries: jax.Array, *, left: bool, interpret
 ) -> jax.Array:
-    n, m = keys.shape[0], queries.shape[0]
-    n_pad = pl.cdiv(max(n, 1), K_TILE) * K_TILE
-    m_pad = pl.cdiv(max(m, 1), Q_BLOCK) * Q_BLOCK
-    keys_p = jnp.full((n_pad,), _PAD_KEY, jnp.int32).at[:n].set(keys.astype(jnp.int32))
-    qs_p = jnp.zeros((m_pad,), jnp.int32).at[:m].set(queries.astype(jnp.int32))
-
-    grid = (m_pad // Q_BLOCK, n_pad // K_TILE)
-    out = pl.pallas_call(
-        functools.partial(_kernel, left=(side == "left")),
+    """Device entry: (M,) counts for tile-aligned padded keys and queries."""
+    grid = (queries.shape[0] // Q_BLOCK, keys.shape[0] // K_TILE)
+    return pl.pallas_call(
+        functools.partial(_kernel, left=left),
         grid=grid,
         in_specs=[
             pl.BlockSpec((K_TILE,), lambda i, j: (j,)),
             pl.BlockSpec((Q_BLOCK,), lambda i, j: (i,)),
         ],
         out_specs=pl.BlockSpec((Q_BLOCK,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((m_pad,), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct(queries.shape, jnp.int32),
         interpret=interpret,
-    )(keys_p, qs_p)
-    return out[:m]
+    )(keys, queries)
+
+
+def sorted_search_pallas(
+    keys, queries, side: str = "left", *, interpret
+) -> np.ndarray:
+    m = len(queries)
+    out = sorted_search_kernel(
+        tiling.pad(keys, K_TILE, _PAD_KEY),
+        tiling.pad(queries, Q_BLOCK, 0),
+        left=(side == "left"),
+        interpret=interpret,
+    )
+    return np.asarray(out)[:m]

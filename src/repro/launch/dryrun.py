@@ -26,8 +26,6 @@ import traceback
 
 import jax
 
-from repro import compat
-
 from repro.configs import ARCH_IDS, all_cells, get_config
 from repro.launch.mesh import make_production_mesh
 from repro.launch.roofline import (
@@ -70,7 +68,7 @@ def run_cell(arch_id: str, shape_name: str, multi_pod: bool, out_dir: str,
 
         def compile_once(arch_):
             bundle = build_step(arch_, shape_name, mesh)
-            with compat.set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 jitted = jax.jit(
                     bundle.fn,
                     in_shardings=bundle.in_shardings,

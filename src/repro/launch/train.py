@@ -13,8 +13,6 @@ import argparse
 import logging
 
 import jax
-
-from repro import compat
 import numpy as np
 
 from repro.configs import ARCH_IDS, get_config
@@ -70,7 +68,7 @@ def run(arch_id: str, shape_name: str, steps: int, ckpt_dir: str,
     mesh = make_smoke_mesh()
     opt_cfg = OptimizerConfig(lr=lr, warmup_steps=max(steps // 20, 5),
                               total_steps=steps)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         bundle = build_step(arch, shape_name, mesh, opt_cfg, use_reduced=True)
         step_jit = jax.jit(bundle.fn, donate_argnums=bundle.donate_argnums)
 

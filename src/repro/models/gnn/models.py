@@ -18,9 +18,8 @@ import math
 from typing import Dict, Optional
 
 import jax
-
-from repro.compat import shard_map
 import jax.numpy as jnp
+from jax import shard_map
 
 from repro.models.gnn import common as C
 

@@ -17,9 +17,8 @@ import math
 from typing import Dict
 
 import jax
-
-from repro.compat import shard_map
 import jax.numpy as jnp
+from jax import shard_map
 
 from repro.models.layers import _dense_init
 from repro.parallel.sharding import MeshAxes, constrain

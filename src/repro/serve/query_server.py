@@ -49,6 +49,8 @@ class RequestResult:
     query_id: str
     n_rows: int
     latency_s: float
+    # the answer: (n_rows, n_projected) int32 dictionary codes
+    rows: Optional[np.ndarray] = None
     # per-request attribution (None/empty when engine telemetry is off)
     trace: Optional[telemetry.QueryTrace] = None
     kernel_dispatches: int = 0
@@ -144,6 +146,7 @@ class QueryServer:
             key,
             res.n_rows,
             latency,
+            rows=res.rows,
             trace=tr,
             kernel_dispatches=tr.ledger.total() if tr is not None else 0,
             kernel_wall_s=tr.ledger.total_wall_s() if tr is not None else 0.0,

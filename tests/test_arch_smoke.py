@@ -5,8 +5,6 @@ The FULL configs are exercised only via the dry-run."""
 import dataclasses
 
 import jax
-
-from repro import compat
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -40,7 +38,7 @@ def test_reduced_train_step(arch_id):
     arch, shape_name = _smoke_arch(arch_id)
     mesh = make_smoke_mesh()
     opt_cfg = OptimizerConfig(warmup_steps=2, total_steps=10)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         bundle = build_step(arch, shape_name, mesh, opt_cfg, use_reduced=True)
         key = jax.random.PRNGKey(0)
         reduced = arch.reduced_model

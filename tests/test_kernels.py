@@ -72,8 +72,8 @@ def _ge_case(rng, kl, kr, nl, nr, c, virtual_frac):
 @pytest.mark.parametrize("kl,kr,nl,nr,c,vf", [
     (1, 1, 1, 1, 1, 0.0),
     (2, 2, 50, 30, 100, 0.0),
-    (3, 4, 700, 300, 1000, 0.25),     # > one output block + virtual rows
-    (2, 2, 1500, 2000, 600, 0.1),     # > one source chunk (N_TILE=512)
+    (3, 4, 700, 300, 1000, 0.25),     # virtual rows
+    (2, 2, 1500, 2000, 600, 0.1),     # > one source chunk
     (4, 1, 64, 64, 5000, 0.0),        # long output
 ])
 def test_gather_emit_sweep(backend, kl, kr, nl, nr, c, vf):
@@ -323,3 +323,59 @@ def test_join_expand_property(data):
                               count, backend=backend)
         np.testing.assert_array_equal(got[0], want[0])
         np.testing.assert_array_equal(got[1], want[1])
+
+
+def _grid_case(kernel, rng):
+    """(numpy outputs, pallas outputs) of one kernel at sizes that span
+    more than one block on both axes of its grid."""
+    if kernel == "sorted_search":
+        keys = np.sort(rng.randint(-500, 500, 5000)).astype(np.int32)
+        qs = rng.randint(-600, 600, 3000).astype(np.int32)
+        return [(ops.sorted_search(keys, qs, "right", backend=be),)
+                for be in ("numpy", "pallas")]
+    if kernel == "hash_probe":
+        bk = rng.randint(-1, 700, 5000).astype(np.int32)
+        qk = rng.randint(-1, 703, 3000).astype(np.int32)
+        out = []
+        for be in ("numpy", "pallas"):
+            order, starts = ops.hash_build(None, bk, 16, backend=be)
+            spid = np.repeat(np.arange(16, dtype=np.int32), np.diff(starts))
+            out.append(ops.hash_probe(spid, None, bk[order], None, qk, starts,
+                                      16, backend=be))
+        return out
+    if kernel == "gather_emit":
+        lcols, rcols, li, ri = _ge_case(rng, 3, 2, 2500, 1800, 3000, 0.1)
+        return [ops.gather_emit(lcols, rcols, li, ri, (0, 1, 2), (0,),
+                                ((2, 1),), backend=be)
+                for be in ("numpy", "pallas")]
+    if kernel == "frontier_dedup":
+        ch, cl = _sorted_pairs(rng, 3000, 60, 60)
+        vh, vl = _sorted_pairs(rng, 5000, 60, 60)
+        keep = vecops.frontier_dedup(vh, vl, vh[:0], vl[:0])
+        vh, vl = vh[keep], vl[keep]
+        return [(ops.frontier_dedup(ch, cl, vh, vl, backend=be),)
+                for be in ("numpy", "pallas")]
+    if kernel == "bloom":
+        keys = rng.randint(0, 1 << 20, 3000).astype(np.int32)
+        qs = rng.randint(0, 1 << 20, 3000).astype(np.int32)
+        out = []
+        for be in ("numpy", "pallas"):
+            words, _, _ = ops.bloom_build(keys, n_words=2048, backend=be)
+            out.append((words, ops.bloom_probe(words, qs, backend=be)))
+        return out
+    raise ValueError(kernel)
+
+
+@pytest.mark.parametrize("kernel", [
+    "sorted_search", "hash_probe", "gather_emit", "frontier_dedup", "bloom",
+])
+def test_pallas_multi_block_grid(kernel):
+    """Every accumulating kernel at more than one block on both grid axes.
+    Off the TPU, Pallas runs in the TPU interpreter, which refuses a grid
+    that leaves an output block and comes back to it: the chip writes an
+    output block back when the grid moves on and never reads it in again,
+    so such a kernel passes the plain interpreter and miscounts on the
+    chip."""
+    want, got = _grid_case(kernel, np.random.RandomState(11))
+    for w, g in zip(want, got):
+        np.testing.assert_array_equal(g, w)
