@@ -12,8 +12,8 @@ the reference: rows exactly, float aggregates to a relative 1e-5 (the
 device path computes in float32).
 
 Earlier lines report the store size, rows and latency per query, the
-programs compiled (fresh and from the persistent cache) and the kernel
-ledger by (kernel, backend). The last line is one JSON object naming the
+programs compiled (fresh and from the persistent cache, counted by the
+kernel that caused them) and the kernel ledger by (kernel, backend). The last line is one JSON object naming the
 device. The run fails, and prints no such line, when JAX finds no TPU,
 when any kernel dispatch of the device passes ran on a backend other than
 pallas, when the reference pass left the numpy plane, or when any answer
@@ -141,36 +141,14 @@ def mismatches(ref: Pass, run: Pass) -> List[str]:
     return [k for k in ref.answers if not same_answer(run.answers[k], ref.answers[k])]
 
 
-class CompileCounter:
-    """Programs compiled or loaded from the persistent cache, from JAX's
-    monitoring events."""
-
-    _COMPILE = "/jax/core/compile/backend_compile_duration"
-    _HIT = "/jax/compilation_cache/cache_hits"
-
-    def __init__(self):
-        self.programs = 0
-        self.cache_hits = 0
-        self.seconds = 0.0
-
-    def on_duration(self, event: str, duration: float, **_kw) -> None:
-        if event == self._COMPILE:
-            self.programs += 1
-            self.seconds += duration
-
-    def on_event(self, event: str, **_kw) -> None:
-        if event == self._HIT:
-            self.cache_hits += 1
-
-    def snapshot(self) -> Tuple[int, int, float]:
-        return self.programs, self.cache_hits, self.seconds
-
-
 def _report_compiles(label: str, before, after) -> None:
     programs = after[0] - before[0]
     hits = after[1] - before[1]
+    by_kernel = collections.Counter(
+        k for k, _, _, _ in telemetry.compile_ledger().events[before[0]:after[0]])
     print(f"compile pass={label} programs={programs} fresh={programs - hits} "
-          f"cache_hits={hits} seconds={after[2] - before[2]}")
+          f"cache_hits={hits} seconds={after[2] - before[2]} "
+          f"by_kernel={dict(by_kernel)}")
 
 
 def main(argv: Optional[Sequence[str]] = None) -> int:
@@ -191,9 +169,8 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
               "count": len(jax.devices())}
     print(f"device {json.dumps(device)}")
     print(f"compile_cache dir={compile_cache.enable()}")
-    counter = CompileCounter()
-    jax.monitoring.register_event_duration_secs_listener(counter.on_duration)
-    jax.monitoring.register_event_listener(counter.on_event)
+    # fed by the kernels package from the first device dispatch on
+    counter = telemetry.compile_ledger()
 
     t0 = time.perf_counter()
     store, meta = generate_ecommerce_graph(scale=SCALE, seed=args.seed)

@@ -23,6 +23,16 @@ buffer to exactly one request:
                    "since process start / last reset" semantics for
                    existing callers, the scoped ledger gives exact
                    per-query attribution even under interleaving.
+  Dispatch       — one kernel dispatch of a traced request as a span: its
+                   id, its parent dispatch's id, the request's trace id,
+                   start and end, the device round trips it made (launch,
+                   wait, copy), host→device, device→host and padding
+                   bytes, and the compiles charged to it. ``phases()``
+                   cuts its self time into stage / launch / wait / copy /
+                   finish (DESIGN.md §13).
+  CompileLedger  — programs compiled (or loaded from the persistent
+                   cache) in the process, each named by the program, the
+                   kernel whose dispatch caused it and its input shapes.
 
 PR 8 adds the workload-history primitives (DESIGN.md §14):
 
@@ -49,11 +59,12 @@ from __future__ import annotations
 
 import collections
 import hashlib
+import itertools
 import json
 import time
 from contextlib import contextmanager
 from contextvars import ContextVar
-from typing import Dict, List, Optional, Tuple
+from typing import Deque, Dict, List, Optional, Tuple
 
 
 class KernelLedger:
@@ -130,10 +141,118 @@ _GLOBAL_LEDGER = KernelLedger()
 _ACTIVE_TRACE: "ContextVar[Optional[QueryTrace]]" = ContextVar(
     "repro_active_trace", default=None
 )
+_ACTIVE_DISPATCH: "ContextVar[Optional[Dispatch]]" = ContextVar(
+    "repro_active_dispatch", default=None
+)
+
+# ids of traces and dispatches, unique in the process
+_IDS = itertools.count(1)
+
+PHASES = ("stage", "launch", "wait", "copy", "finish")
+
+
+class Dispatch:
+    """One kernel dispatch of a traced request, as a span.
+
+    ``trips`` holds each device round trip the dispatch made
+    (``kernels.tiling.round_trip``) as perf_counter instants (start,
+    launched, waited, copied): ``launch`` is the jitted call (argument
+    transfer, dispatch and any compile), ``wait`` the block until the
+    outputs are ready, ``copy`` their copy to host numpy. ``kids`` holds
+    the (start, end) of each child dispatch (``hash_build`` →
+    ``radix_partition``), whose time is theirs and not this one's."""
+
+    __slots__ = ("id", "parent", "trace_id", "kernel", "backend", "t0", "t1",
+                 "trips", "kids", "h2d_bytes", "d2h_bytes", "pad_logical_bytes",
+                 "pad_bytes", "compiles", "cache_hits", "inputs")
+
+    def __init__(self, kernel: str, backend: str, trace_id: int,
+                 parent: Optional[int] = None) -> None:
+        self.id = next(_IDS)
+        self.parent = parent
+        self.trace_id = trace_id
+        self.kernel = kernel
+        self.backend = backend
+        self.t0 = self.t1 = 0.0
+        self.trips: List[Tuple[float, float, float, float]] = []
+        self.kids: List[Tuple[float, float]] = []
+        # host numpy arguments handed to the device / outputs copied back
+        self.h2d_bytes = self.d2h_bytes = 0
+        # bytes before and after ``kernels.tiling.pad``
+        self.pad_logical_bytes = self.pad_bytes = 0
+        # (program, seconds, input shapes) of each compile it caused
+        self.compiles: List[Tuple[str, float, tuple]] = []
+        self.cache_hits = 0
+        # the round trip in flight's arguments, whose shapes a compile names
+        self.inputs: tuple = ()
+
+    def add_trip(self, t0: float, t1: float, t2: float, t3: float,
+                 h2d_bytes: int, d2h_bytes: int) -> None:
+        self.trips.append((t0, t1, t2, t3))
+        self.h2d_bytes += h2d_bytes
+        self.d2h_bytes += d2h_bytes
+
+    def phases(self) -> Dict[str, float]:
+        """Self time (the span less its child dispatches) in seconds by
+        phase: ``launch``, ``wait`` and ``copy`` of its round trips;
+        ``finish`` after its last round trip; ``stage`` the rest. With no
+        round trip (numpy plane, empty-input shortcuts) it is all
+        ``stage``."""
+        launch = wait = copy = 0.0
+        for a, b, c, e in self.trips:
+            launch += b - a
+            wait += c - b
+            copy += e - c
+        end = self.trips[-1][3] if self.trips else self.t1
+        before = sum(b - a for a, b in self.kids if b <= end)
+        after = sum(b - a for a, b in self.kids if b > end)
+        return {
+            "stage": (end - self.t0) - before - launch - wait - copy,
+            "launch": launch,
+            "wait": wait,
+            "copy": copy,
+            "finish": (self.t1 - end) - after,
+        }
+
+
+class CompileLedger:
+    """Programs compiled in the process, fresh or loaded from JAX's
+    persistent cache (both end in one ``backend_compile_duration`` event;
+    a load also raises ``cache_hits``). Each event is (kernel, program,
+    seconds, input shapes); the kernel is the dispatch that caused it,
+    None outside one."""
+
+    __slots__ = ("programs", "cache_hits", "seconds", "events")
+
+    def __init__(self) -> None:
+        self.programs = 0
+        self.cache_hits = 0
+        self.seconds = 0.0
+        self.events: List[Tuple[Optional[str], str, float, tuple]] = []
+
+    def snapshot(self) -> Tuple[int, int, float]:
+        """(programs, cache hits, compile seconds) so far."""
+        return self.programs, self.cache_hits, self.seconds
+
+
+_COMPILES = CompileLedger()
+
+# the last request traces the server finished, newest last: what the
+# process can still say about its recent requests after the server that
+# served them is gone. Bounded, as a trace holds every dispatch span:
+# ~0.3 MB for a BSBM explore request's ~440 dispatches.
+RECENT_TRACES = 128
+_RECENT: Deque["QueryTrace"] = collections.deque(maxlen=RECENT_TRACES)
 
 
 def global_ledger() -> KernelLedger:
     return _GLOBAL_LEDGER
+
+
+def compile_ledger() -> CompileLedger:
+    """The process-global compile ledger (fed by the kernels package's
+    ``jax.monitoring`` listener from the first device dispatch on)."""
+    return _COMPILES
 
 
 def current_trace() -> Optional["QueryTrace"]:
@@ -141,22 +260,94 @@ def current_trace() -> Optional["QueryTrace"]:
     return _ACTIVE_TRACE.get()
 
 
+def current_dispatch() -> Optional[Dispatch]:
+    """The innermost kernel dispatch in flight under the active trace."""
+    return _ACTIVE_DISPATCH.get()
+
+
+def retain(trace: "QueryTrace") -> None:
+    """Keep a finished request's trace among the recent ones."""
+    _RECENT.append(trace)
+
+
+def recent_traces() -> List["QueryTrace"]:
+    """The last ``RECENT_TRACES`` finished request traces, oldest first."""
+    return list(_RECENT)
+
+
 def record_dispatch(name: str, backend: str, t0: float, dt: float) -> None:
-    """Attribute one kernel dispatch: to the active query trace when one
-    is installed, and always to the process-global ledger."""
+    """Attribute one kernel dispatch timed by the caller: to the active
+    query trace when one is installed, and always to the process-global
+    ledger."""
     tr = _ACTIVE_TRACE.get()
     if tr is not None:
         tr.ledger.record(name, backend, dt)
         if tr.kernel_events:
-            tr._kernels.append((name, backend, t0, dt))
+            parent = _ACTIVE_DISPATCH.get()
+            d = Dispatch(name, backend, tr.id, None if parent is None else parent.id)
+            d.t0, d.t1 = t0, t0 + dt
+            tr.dispatches.append(d)
     _GLOBAL_LEDGER.record(name, backend, dt)
+
+
+def open_dispatch(tr: "QueryTrace", kernel: str, backend: str):
+    """Start a dispatch span under ``tr``, child of the dispatch in flight;
+    returns it with the token ``close_dispatch`` takes."""
+    parent = _ACTIVE_DISPATCH.get()
+    d = Dispatch(kernel, backend, tr.id, None if parent is None else parent.id)
+    token = _ACTIVE_DISPATCH.set(d)
+    d.t0 = time.perf_counter()
+    return d, token
+
+
+def close_dispatch(tr: "QueryTrace", d: Dispatch, token) -> None:
+    """End ``d``: charge its span to its parent as child time, and record
+    it in the trace's and the process-global ledgers."""
+    d.t1 = time.perf_counter()
+    _ACTIVE_DISPATCH.reset(token)
+    parent = _ACTIVE_DISPATCH.get()
+    if parent is not None:
+        parent.kids.append((d.t0, d.t1))
+    dt = d.t1 - d.t0
+    tr.ledger.record(d.kernel, d.backend, dt)
+    if tr.kernel_events:
+        tr.dispatches.append(d)
+    _GLOBAL_LEDGER.record(d.kernel, d.backend, dt)
+
+
+def record_compile(program: str, seconds: float) -> None:
+    """Charge one compile to the dispatch in flight (with its inputs'
+    shapes), else to the active request, and always to the process-global
+    compile ledger."""
+    d = _ACTIVE_DISPATCH.get()
+    shapes = () if d is None else tuple(tuple(getattr(a, "shape", ())) for a in d.inputs)
+    if d is not None:
+        d.compiles.append((program, seconds, shapes))
+    else:
+        tr = _ACTIVE_TRACE.get()
+        if tr is not None:
+            tr.compiles.append((program, seconds, shapes))
+    _COMPILES.programs += 1
+    _COMPILES.seconds += seconds
+    _COMPILES.events.append((None if d is None else d.kernel, program, seconds, shapes))
+
+
+def record_cache_hit() -> None:
+    """Count one program loaded from the persistent compilation cache."""
+    d = _ACTIVE_DISPATCH.get()
+    if d is not None:
+        d.cache_hits += 1
+    else:
+        tr = _ACTIVE_TRACE.get()
+        if tr is not None:
+            tr.cache_hits += 1
+    _COMPILES.cache_hits += 1
 
 
 @contextmanager
 def trace_query(label: str = "query", trace: Optional["QueryTrace"] = None):
-    """Install ``trace`` (or a fresh QueryTrace) as the active attribution
-    scope. ``trace=None`` with a falsy label yields None and installs
-    nothing — callers can pass a disabled trace straight through."""
+    """Install ``trace`` (or a fresh QueryTrace labelled ``label``) as the
+    active attribution scope."""
     tr = trace if trace is not None else QueryTrace(label)
     token = _ACTIVE_TRACE.set(tr)
     try:
@@ -174,16 +365,28 @@ class QueryTrace:
     """Span + kernel-event recorder for one query execution."""
 
     def __init__(self, label: str = "query", kernel_events: bool = True) -> None:
+        self.id = next(_IDS)
         self.label = label
         self.kernel_events = kernel_events
         self.ledger = KernelLedger()
         self.t0 = time.perf_counter()
         # (name, category, start_s, dur_s, args) — start in perf_counter time
         self.spans: List[Tuple[str, str, float, float, dict]] = []
-        # (kernel, backend, start_s, dur_s)
-        self._kernels: List[Tuple[str, str, float, float]] = []
+        # every kernel dispatch, in the order they ended (children first)
+        self.dispatches: List[Dispatch] = []
+        # compiles outside any dispatch: (program, seconds, input shapes)
+        self.compiles: List[Tuple[str, float, tuple]] = []
+        self.cache_hits = 0
         # (label, depth, start_s, dur_s, args) — synthesized operator lane
         self._operators: List[Tuple[str, float, float, dict]] = []
+
+    def phase_totals(self) -> Dict[str, float]:
+        """Seconds of the request's dispatch self time in each phase."""
+        out = dict.fromkeys(PHASES, 0.0)
+        for d in self.dispatches:
+            for k, v in d.phases().items():
+                out[k] += v
+        return out
 
     # -- recording ----------------------------------------------------------
 
@@ -261,19 +464,16 @@ class QueryTrace:
                     "args": dict(args),
                 }
             )
-        for kname, backend, t0, dur in self._kernels:
-            ev.append(
-                {
-                    "name": kname,
-                    "cat": "kernel",
-                    "ph": "X",
-                    "ts": self._us(t0),
-                    "dur": dur * 1e6,
-                    "pid": 1,
-                    "tid": _TID_KERNELS,
-                    "args": {"backend": backend},
-                }
-            )
+        for d in self.dispatches:
+            ev.append(self._kernel_event(d.kernel, "kernel", d.t0, d.t1, {
+                "backend": d.backend, "id": d.id, "parent": d.parent,
+                "trace": d.trace_id, "h2d_bytes": d.h2d_bytes,
+                "d2h_bytes": d.d2h_bytes, "pad_logical_bytes": d.pad_logical_bytes,
+                "pad_bytes": d.pad_bytes, "cache_hits": d.cache_hits,
+                "compiles": [[p, s] for p, s, _ in d.compiles],
+                "self_ms": {k: v * 1e3 for k, v in d.phases().items()},
+            }))
+            ev.extend(self._phase_events(d))
         for label, t0, dur, args in self._operators:
             ev.append(
                 {
@@ -287,6 +487,30 @@ class QueryTrace:
                     "args": dict(args),
                 }
             )
+        return ev
+
+    def _kernel_event(self, name: str, cat: str, t0: float, t1: float,
+                      args: dict) -> dict:
+        return {"name": name, "cat": cat, "ph": "X", "ts": self._us(t0),
+                "dur": (t1 - t0) * 1e6, "pid": 1, "tid": _TID_KERNELS,
+                "args": args}
+
+    def _phase_events(self, d: Dispatch) -> List[dict]:
+        """A dispatch's phases as events nested in its own: ``stage``
+        before and between its round trips, each trip's ``launch``,
+        ``wait`` and ``copy``, and ``finish`` after the last (stage and
+        finish events hold any child dispatch in their interval)."""
+        ev = []
+        t = d.t0
+        for a, b, c, e in d.trips:
+            if a > t:
+                ev.append(self._kernel_event("stage", "phase", t, a, {"kernel": d.kernel}))
+            for name, x, y in (("launch", a, b), ("wait", b, c), ("copy", c, e)):
+                ev.append(self._kernel_event(name, "phase", x, y, {"kernel": d.kernel}))
+            t = e
+        name = "finish" if d.trips else "stage"
+        if d.t1 > t:
+            ev.append(self._kernel_event(name, "phase", t, d.t1, {"kernel": d.kernel}))
         return ev
 
     def to_chrome_trace(self) -> dict:
@@ -305,13 +529,17 @@ class QueryTrace:
             f.write(self.chrome_json())
 
     def summary(self) -> dict:
-        """Compact JSON-able digest: span durations + the kernel ledger."""
+        """Compact JSON-able digest: span durations, the kernel ledger, and
+        the dispatches' self time by phase and their byte counts."""
         return {
             "query": self.label,
             "spans_ms": {
                 name: round(dur * 1e3, 4) for name, _c, _t, dur, _a in self.spans
             },
             "kernels": self.ledger.snapshot(),
+            "phases_ms": {k: round(v * 1e3, 4) for k, v in self.phase_totals().items()},
+            "h2d_bytes": sum(d.h2d_bytes for d in self.dispatches),
+            "d2h_bytes": sum(d.d2h_bytes for d in self.dispatches),
         }
 
 

@@ -105,10 +105,11 @@ def bloom_build_kernel(keys: jax.Array, *, n_words: int, interpret
 def bloom_build_pallas(keys, n_words: int, *, interpret) -> np.ndarray:
     """(n_words,) uint32 filter words — see vecops.bloom_build."""
     assert n_words & (n_words - 1) == 0, "n_words must be a power of two"
-    words = bloom_build_kernel(
-        tiling.pad(keys, K_BLOCK, _PAD), n_words=n_words, interpret=interpret
+    words = tiling.round_trip(
+        bloom_build_kernel, tiling.pad(keys, K_BLOCK, _PAD), n_words=n_words,
+        interpret=interpret,
     )
-    return np.asarray(words)[:n_words].view(np.uint32)
+    return words[:n_words].view(np.uint32)
 
 
 def _probe_kernel(words_ref, q_ref, out_ref, *, n_words: int):
@@ -159,10 +160,11 @@ def bloom_probe_pallas(words, queries, *, interpret) -> np.ndarray:
     n_words = len(words)
     c = len(queries)
     words = np.asarray(words, np.uint32).view(np.int32)
-    mask = bloom_probe_kernel(
+    mask = tiling.round_trip(
+        bloom_probe_kernel,
         tiling.pad(words, W_TILE, 0),
         tiling.pad(queries, Q_BLOCK, _PAD),
         n_words=n_words,
         interpret=interpret,
     )
-    return np.asarray(mask)[:c]
+    return mask[:c]

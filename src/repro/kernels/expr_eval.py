@@ -73,10 +73,11 @@ def expr_eval_pallas(icols, fcols, prog: ExprProgram, *, interpret):
     n = icols.shape[1]
     # padding rows: NULL codes / NaN values — they evaluate to errors that
     # the final slice drops
-    val, err = expr_eval_kernel(
+    val, err = tiling.round_trip(
+        expr_eval_kernel,
         tiling.pad(icols, BLOCK, -1),
         tiling.pad(fcols, BLOCK, np.nan, np.float32),
         prog=prog,
         interpret=interpret,
     )
-    return np.asarray(val)[:n], np.asarray(err)[:n]
+    return val[:n], err[:n]

@@ -76,7 +76,8 @@ def frontier_dedup_pallas(cand_hi, cand_lo, vis_hi, vis_lo, *, interpret
     # left-neighbor columns; the first candidate gets a sentinel neighbor
     ph = np.concatenate([[_PAD], cand_hi[:-1]]).astype(np.int32)
     pl_ = np.concatenate([[_PAD], cand_lo[:-1]]).astype(np.int32)
-    counts = frontier_dedup_kernel(
+    counts = tiling.round_trip(
+        frontier_dedup_kernel,
         tiling.pad(vis_hi, V_TILE, _PAD),
         tiling.pad(vis_lo, V_TILE, _PAD),
         tiling.pad(cand_hi, C_BLOCK, _PAD),
@@ -85,4 +86,4 @@ def frontier_dedup_pallas(cand_hi, cand_lo, vis_hi, vis_lo, *, interpret
         tiling.pad(pl_, C_BLOCK, _PAD),
         interpret=interpret,
     )
-    return np.asarray(counts)[:c] == 0
+    return counts[:c] == 0

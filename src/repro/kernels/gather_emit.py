@@ -134,9 +134,8 @@ def gather_emit_pallas(lsrc, rsrc, li, ri, n_pairs: int, *, interpret
     rsrc = tiling.pad(rsrc, tiling.bucket(n, N_TILE), 0)
     # pad li with 0 (a real row; the padded output slots are sliced off) and
     # ri with -1 (virtual, selects nothing)
-    lout, rout, mask = gather_emit_kernel(
-        lsrc, rsrc, tiling.pad(li, BLOCK, 0), tiling.pad(ri, BLOCK, _NULL),
-        n_pairs=n_pairs, interpret=interpret,
+    lout, rout, mask = tiling.round_trip(
+        gather_emit_kernel, lsrc, rsrc, tiling.pad(li, BLOCK, 0),
+        tiling.pad(ri, BLOCK, _NULL), n_pairs=n_pairs, interpret=interpret,
     )
-    return (np.asarray(lout)[:, :c], np.asarray(rout)[:, :c],
-            np.asarray(mask)[:c])
+    return lout[:, :c], rout[:, :c], mask[:c]

@@ -106,7 +106,8 @@ def hash_probe_pallas(bpid, bhi, blo, qpid, qhi, qlo, *, interpret
     """Returns (lo, hi) int32 run boundaries per probe key. Build padding
     rows carry pid = INT32_MAX and count for no probe."""
     c = len(qpid)
-    lo, hi = hash_probe_kernel(
+    lo, hi = tiling.round_trip(
+        hash_probe_kernel,
         tiling.pad(bpid, N_TILE, _PAD_PID),
         tiling.pad(bhi, N_TILE, 0),
         tiling.pad(blo, N_TILE, 0),
@@ -115,4 +116,4 @@ def hash_probe_pallas(bpid, bhi, blo, qpid, qhi, qlo, *, interpret
         tiling.pad(qlo, BLOCK, 0),
         interpret=interpret,
     )
-    return np.asarray(lo)[:c], np.asarray(hi)[:c]
+    return lo[:c], hi[:c]

@@ -112,7 +112,8 @@ def join_expand_pallas(
     total = int(cum[-1])
     # padded groups are empty and start at the total: no slot below the
     # total ever selects one
-    li, ri = join_expand_kernel(
+    li, ri = tiling.round_trip(
+        join_expand_kernel,
         tiling.pad(cum[1:], BLOCK, total),
         tiling.pad(cum[:-1], BLOCK, total),
         tiling.pad(lstarts, BLOCK, 0),
@@ -123,4 +124,4 @@ def join_expand_pallas(
         n_out=tiling.bucket(count, BLOCK),
         interpret=interpret,
     )
-    return np.asarray(li)[:count], np.asarray(ri)[:count]
+    return li[:count], ri[:count]

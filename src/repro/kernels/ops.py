@@ -105,14 +105,19 @@ def _backend(override: Optional[str]) -> str:
 
 def _ledgered(fn):
     """Instrument a public kernel wrapper: one ledger entry (count + wall
-    seconds, keyed by kernel name and resolved backend) per call, routed
-    through telemetry.record_dispatch — the active query trace if one is
-    installed, always the process-global ledger. Wall time is inclusive:
-    wrappers that internally dispatch other wrappers (hash_build →
-    radix_partition) tick both entries, exactly as the pre-§13 counters
-    did."""
+    seconds, keyed by kernel name and resolved backend) per call in the
+    process-global ledger and, under an active query trace, in the trace's
+    own. Wall time is inclusive: wrappers that internally dispatch other
+    wrappers (hash_build → radix_partition) tick both entries, exactly as
+    the pre-§13 counters did.
+
+    Under an active trace each call is also a ``telemetry.Dispatch`` span
+    (id, parent dispatch, the trace's id), which ``tiling.pad`` and
+    ``tiling.round_trip`` fill with padding bytes and device round trips,
+    inside a ``jax.profiler.TraceAnnotation`` named ``barq.<kernel>``."""
     bidx = list(inspect.signature(fn).parameters).index("backend")
     name = fn.__name__
+    label = tiling.ANNOTATION_PREFIX + name
 
     @functools.wraps(fn)
     def wrapper(*args, **kwargs):
@@ -120,11 +125,19 @@ def _ledgered(fn):
         if be is None and len(args) > bidx:
             be = args[bidx]
         be = be or default_backend()
-        t0 = time.perf_counter()
+        tr = telemetry.current_trace()
+        if tr is None:
+            t0 = time.perf_counter()
+            try:
+                return fn(*args, **kwargs)
+            finally:
+                telemetry.record_dispatch(name, be, t0, time.perf_counter() - t0)
+        d, token = telemetry.open_dispatch(tr, name, be)
         try:
-            return fn(*args, **kwargs)
+            with tiling.annotation()(label):
+                return fn(*args, **kwargs)
         finally:
-            telemetry.record_dispatch(name, be, t0, time.perf_counter() - t0)
+            telemetry.close_dispatch(tr, d, token)
 
     return wrapper
 
@@ -431,11 +444,12 @@ def hash_build(
         )
         # bucketed like the kernels; padding rows sort after every real one
         n = len(key_lo)
-        order = np.asarray(ref.hash_build_order(
+        order = tiling.round_trip(
+            ref.hash_build_order,
             tiling.pad(pid, tiling.SORT_TILE, np.iinfo(np.int32).max),
             tiling.pad(hi, tiling.SORT_TILE, 0),
             tiling.pad(key_lo, tiling.SORT_TILE, 0),
-        ))[:n]
+        )[:n]
     else:
         raise ValueError(be)
     return order, part_starts

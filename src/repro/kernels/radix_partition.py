@@ -70,7 +70,8 @@ def radix_partition_pallas(keys, n_parts: int, *, interpret
                            ) -> Tuple[np.ndarray, np.ndarray]:
     assert n_parts & (n_parts - 1) == 0, "n_parts must be a power of two"
     n = len(keys)
-    pid, hist = radix_partition_kernel(
-        tiling.pad(keys, BLOCK, _PAD), n_parts=n_parts, interpret=interpret
+    pid, hist = tiling.round_trip(
+        radix_partition_kernel, tiling.pad(keys, BLOCK, _PAD), n_parts=n_parts,
+        interpret=interpret,
     )
-    return np.asarray(pid)[:n], np.asarray(hist)
+    return pid[:n], hist

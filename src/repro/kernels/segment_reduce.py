@@ -116,10 +116,11 @@ def segment_scan_pallas(keys, values, op: str = "sum", *, interpret
     """(N,) float32 segmented inclusive scan; padding rows carry a key
     below every real one and the identity value."""
     n = len(keys)
-    out = segment_scan_kernel(
+    out = tiling.round_trip(
+        segment_scan_kernel,
         tiling.pad(keys, BLOCK, _SENTINEL + 1).reshape(-1, LANES),
         tiling.pad(values, BLOCK, _IDENT[op], np.float32).reshape(-1, LANES),
         op=op,
         interpret=interpret,
     )
-    return np.asarray(out).reshape(-1)[:n]
+    return out.reshape(-1)[:n]

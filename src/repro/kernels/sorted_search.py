@@ -63,10 +63,11 @@ def sorted_search_pallas(
     keys, queries, side: str = "left", *, interpret
 ) -> np.ndarray:
     m = len(queries)
-    out = sorted_search_kernel(
+    out = tiling.round_trip(
+        sorted_search_kernel,
         tiling.pad(keys, K_TILE, _PAD_KEY),
         tiling.pad(queries, Q_BLOCK, 0),
         left=(side == "left"),
         interpret=interpret,
     )
-    return np.asarray(out)[:m]
+    return out[:m]

@@ -27,6 +27,7 @@ with the cardinalities its previous runs actually observed.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import hashlib
 import time
@@ -64,6 +65,10 @@ class RequestResult:
     flight_bundle: Optional[str] = None
 
 
+def _span(trace: Optional[telemetry.QueryTrace], name: str):
+    return trace.span(name) if trace is not None else contextlib.nullcontext()
+
+
 class QueryServer:
     def __init__(
         self,
@@ -83,7 +88,8 @@ class QueryServer:
         self._plan_cache: Dict[str, Tuple[PL.Phys, A.VarTable, str]] = {}
         self.metrics = MetricsRegistry()
 
-    def _plan_for(self, text: str) -> Tuple[PL.Phys, A.VarTable, str]:
+    def _plan_for(self, text: str, trace: Optional[telemetry.QueryTrace] = None
+                  ) -> Tuple[PL.Phys, A.VarTable, str]:
         # cache key is a hash of the query text itself — the caller's
         # query_id is a reporting label only, so two different queries
         # sharing an id can never silently reuse the wrong cached plan.
@@ -91,24 +97,33 @@ class QueryServer:
         # folded in too: swapping the engine config must not serve a plan
         # shaped under the old knobs, and under feedback=apply it advances
         # with the feedback store's version so new observations re-plan.
+        t0 = time.perf_counter()
         key = hashlib.sha256(
             f"{self.engine.plan_fingerprint()}\n{text}".encode()
         ).hexdigest()
         hit = self._plan_cache.get(key)
         self.metrics.observe_plan_cache(hit is not None)
+        if trace is not None:
+            trace.add_span("plan_cache", "query", t0, time.perf_counter() - t0,
+                           hit=hit is not None)
         if hit is None:
-            node, vt = self.engine.parse(text)
-            hit = (self.engine.plan(node), vt, telemetry.query_fingerprint(node))
+            with _span(trace, "parse"):
+                node, vt = self.engine.parse(text)
+            with _span(trace, "plan"):
+                hit = (self.engine.plan(node), vt, telemetry.query_fingerprint(node))
             self._plan_cache[key] = hit
         return hit
 
     def execute(self, key: str, text: str) -> RequestResult:
         t0 = time.perf_counter()
         misses_before = self.metrics.plan_cache_misses
-        phys, vt, qfp = self._plan_for(text)
-        res = self.engine.execute_plan(phys, vt)
+        # the request's trace holds its parse and plan spans too, and every
+        # kernel dispatch of its execution
+        tr = telemetry.QueryTrace(key) if self.engine.cfg.telemetry else None
+        with telemetry.trace_query(trace=tr) if tr is not None else contextlib.nullcontext():
+            phys, vt, qfp = self._plan_for(text, tr)
+        res = self.engine.execute_plan(phys, vt, trace=tr)
         latency = time.perf_counter() - t0
-        tr = res.trace
         pool_delta = res.pool_delta()
         stats = profiler.collect_stats(res.root)
         self.metrics.observe_request(
@@ -142,6 +157,8 @@ class QueryServer:
                 explain_fn=res.explain_analyze,
                 query_text=text,
             )
+        if tr is not None:
+            telemetry.retain(tr)
         return RequestResult(
             key,
             res.n_rows,
