@@ -168,12 +168,29 @@ class QuadStore:
         hi = min(lo + count, rng.hi)
         return self._indexes[rng.index][lo:hi]
 
-    def seek(self, rng: ScanRange, start: int, sort_col_pos: int, target: int) -> int:
+    def seek(self, rng: ScanRange, start: int, sort_col_pos: int, target: int,
+             side: str = "left") -> int:
         """skip(): offset (>= start) of first row whose key at ``sort_col_pos``
-        within the index order is >= target. This is the RocksDB seek
-        analogue the BARQ merge join drives (paper §3.2 Skip phase)."""
+        within the index order is >= target (> target with ``side="right"``).
+        This is the RocksDB seek analogue the BARQ merge join drives (paper
+        §3.2 Skip phase)."""
         col = self._index_cols[rng.index][sort_col_pos][rng.lo + start : rng.hi]
-        return start + int(np.searchsorted(col, np.int32(target), side="left"))
+        return start + int(np.searchsorted(col, np.int32(target), side=side))
+
+    def seek_keys(self, rng: ScanRange, start: int, stop: int, sort_col_pos: int,
+                  keys: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+        """seek() for many sorted keys at once: the offsets (in [start,
+        stop)) of the first row of each key's run in the sort column and
+        of the row after its last. A key with no rows gets an empty run."""
+        col = self._index_cols[rng.index][sort_col_pos][rng.lo + start : rng.lo + stop]
+        keys = np.asarray(keys, dtype=col.dtype)
+        return (start + np.searchsorted(col, keys, side="left"),
+                start + np.searchsorted(col, keys, side="right"))
+
+    def take(self, rng: ScanRange, offsets: np.ndarray) -> np.ndarray:
+        """The rows at ``offsets`` within the range, in index order
+        (permuted columns), as a copy."""
+        return self._indexes[rng.index][rng.lo + offsets]
 
     # -- stats for the optimizer ------------------------------------------------
 

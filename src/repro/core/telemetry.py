@@ -12,8 +12,10 @@ buffer to exactly one request:
                    Counter IS that object); one per-query instance lives
                    on each QueryTrace.
   QueryTrace     — span recorder for the query lifecycle (parse → plan →
-                   translate → execute), a per-query KernelLedger, and a
-                   per-dispatch kernel event log. Exports Chrome-trace
+                   translate → execute), a per-query KernelLedger, a
+                   per-dispatch kernel event log, and the rows and key
+                   seeks of SIP-consuming scans (``count_sip_scan``).
+                   Exports Chrome-trace
                    JSON (``chrome-tracing`` / Perfetto ``traceEvents``
                    format) so traces open directly in ui.perfetto.dev.
   trace_query()  — contextvar scope installing a QueryTrace as the active
@@ -315,6 +317,15 @@ def close_dispatch(tr: "QueryTrace", d: Dispatch, token) -> None:
     _GLOBAL_LEDGER.record(d.kernel, d.backend, dt)
 
 
+def count_sip_scan(rows: int, seeks: int = 0) -> None:
+    """Charge rows an index scan read from the store while consuming a SIP
+    filter, and the key seeks it took, to the active request's trace."""
+    tr = _ACTIVE_TRACE.get()
+    if tr is not None:
+        tr.sip_scan_rows += rows
+        tr.sip_seeks += seeks
+
+
 def record_compile(program: str, seconds: float) -> None:
     """Charge one compile to the dispatch in flight (with its inputs'
     shapes), else to the active request, and always to the process-global
@@ -377,6 +388,10 @@ class QueryTrace:
         # compiles outside any dispatch: (program, seconds, input shapes)
         self.compiles: List[Tuple[str, float, tuple]] = []
         self.cache_hits = 0
+        # rows read by index scans consuming a SIP filter, and the scans
+        # that sought the build keys' runs (``count_sip_scan``)
+        self.sip_scan_rows = 0
+        self.sip_seeks = 0
         # (label, depth, start_s, dur_s, args) — synthesized operator lane
         self._operators: List[Tuple[str, float, float, dict]] = []
 
@@ -474,6 +489,10 @@ class QueryTrace:
                 "self_ms": {k: v * 1e3 for k, v in d.phases().items()},
             }))
             ev.extend(self._phase_events(d))
+        end = max((t0 + dur for _n, _c, t0, dur, _a in self.spans), default=self.t0)
+        ev.append({"name": "sip_scan", "ph": "C", "ts": self._us(end), "pid": 1,
+                   "args": {"sip_scan_rows": self.sip_scan_rows,
+                            "sip_seeks": self.sip_seeks}})
         for label, t0, dur, args in self._operators:
             ev.append(
                 {
@@ -540,6 +559,8 @@ class QueryTrace:
             "phases_ms": {k: round(v * 1e3, 4) for k, v in self.phase_totals().items()},
             "h2d_bytes": sum(d.h2d_bytes for d in self.dispatches),
             "d2h_bytes": sum(d.d2h_bytes for d in self.dispatches),
+            "sip_scan_rows": self.sip_scan_rows,
+            "sip_seeks": self.sip_seeks,
         }
 
 

@@ -13,7 +13,9 @@ from repro.core import Engine, EngineConfig, QuadStore
 from repro.core import planner as PL
 from repro.core import vecops
 from repro.core.batch import NULL_ID
+from repro.core.operators import scan as scan_mod
 from repro.core.operators.scan import IndexScan
+from repro.core.adaptive import AdaptiveBatchSizer
 from repro.core.algebra import K, TriplePattern, V
 from repro.core.sip import SipFilter
 from repro.kernels import ops
@@ -140,6 +142,19 @@ def test_sip_filter_empty_build_prunes_everything():
     assert not f.mask(np.arange(100, dtype=np.int32)).any()
 
 
+@pytest.mark.parametrize("distinct", [3, 20])
+def test_sip_filter_bloom_sized_by_build_rows(distinct):
+    """The bloom's word count, a compiled shape on a device plane, follows
+    the build side's rows, not its distinct keys, so two requests of one
+    template with 20-row build sides share one program."""
+    keys = np.arange(20, dtype=np.int32) % distinct
+    f = SipFilter(var=0)
+    f.bind(lambda: ("keys", keys))
+    f.mask(keys)
+    assert len(f.keys) == distinct
+    assert len(f.words) == vecops.bloom_n_words(20)
+
+
 def test_sip_filter_range_only_provider():
     f = SipFilter(var=0)
     f.bind(lambda: ("range", 5, 9))
@@ -229,6 +244,262 @@ def test_scan_sip_range_narrowing_cuts_reads():
     assert n <= 10
     assert scan.stats.rows_scanned < 200
     assert scan.stats.extra.get("sip_range_seeks", 0) == 1
+
+
+# ---------------------------------------------------------------------------
+# scan integration: seeks straight to the build keys' runs
+# ---------------------------------------------------------------------------
+
+
+def _seek_store():
+    """1,000 subjects of :p with three objects each (3,000 rows sorted by
+    subject)."""
+    store = QuadStore()
+    for i in range(1000):
+        for j in range(3):
+            store.add(f":s{i:04d}", ":p", f":o{(i + j) % 11}")
+    return store.build()
+
+
+def _codes(store, names):
+    return np.asarray([store.dict.lookup(n) for n in names], np.int32)
+
+
+def _keys_filter(keys, var=0):
+    f = SipFilter(var=var)
+    f.bind(lambda: ("keys", np.asarray(keys, np.int32)))
+    return f
+
+
+def _drain(scan):
+    """Active rows as (var -> column) over the whole scan."""
+    out = {v: [] for v in scan.var_ids()}
+    while True:
+        b = scan.next_batch()
+        if b is None:
+            break
+        live = b.mask[: b.n_rows]
+        for v in out:
+            out[v].append(b.columns[b.col_index(v), : b.n_rows][live])
+        b.release()
+    return {v: np.concatenate(c) if c else np.zeros(0, np.int32) for v, c in out.items()}
+
+
+P_SO = TriplePattern(V(0), K(":p"), V(1))
+
+
+def _sip_scan(store, *filters):
+    return IndexScan(store, P_SO, want_sorted_var=0, sip_filters=list(filters))
+
+
+def _range_rows(store, scan):
+    """Rows of the scan's SIP code range: what the seek-or-range rule
+    weighs the key count against."""
+    f = scan.sip_filters[0]
+    lo, hi = f.code_range()
+    start = store.seek(scan.range, 0, scan._sort_col_pos, lo)
+    return store.seek(scan.range, start, scan._sort_col_pos, hi, "right") - start
+
+
+def test_scan_sip_key_seek_returns_the_range_and_bloom_rows(monkeypatch):
+    """Under a sparse build side the sorted scan seeks each key's run; it
+    returns exactly the members the range + bloom path keeps, with none of
+    the bloom's false positives, and reads only their rows."""
+    store = _seek_store()
+    keys = _codes(store, [":s0007", ":s0410", ":s0411", ":s0993"])
+    seek = _sip_scan(store, _keys_filter(keys))
+    got = _drain(seek)
+    monkeypatch.setattr(scan_mod, "SEEK_ROWS_PER_KEY", 1 << 40)  # always range + bloom
+    rng = _sip_scan(store, _keys_filter(keys))
+    ref = _drain(rng)
+    member = np.isin(ref[0], keys)
+    np.testing.assert_array_equal(got[0], ref[0][member])
+    np.testing.assert_array_equal(got[1], ref[1][member])
+    assert len(got[0]) == 12 and set(got[0].tolist()) == set(keys.tolist())
+    assert seek.stats.rows_scanned == 12
+    assert rng.stats.rows_scanned > 2000
+    assert seek.stats.extra["sip_key_seeks"] == 1
+    assert "sip_key_seeks" not in rng.stats.extra
+    assert rng.stats.extra["sip_probe_dispatches"] > 0
+
+
+def _seeks(k, n):
+    return k * scan_mod.SEEK_ROWS_PER_KEY < n + scan_mod.SEEK_FIXED_ROWS
+
+
+@pytest.mark.parametrize("sparse", [True, False])
+def test_scan_sip_seek_rule_weighs_keys_against_range_rows(sparse):
+    """Seek iff keys × SEEK_ROWS_PER_KEY < rows in the code range +
+    SEEK_FIXED_ROWS: two keys at the ends of a 2,400-row range seek;
+    every subject of :p (1,000 keys, 3,000 rows) keeps range + bloom."""
+    store = _seek_store()
+    names = ([":s0100", ":s0899"] if sparse
+             else [f":s{i:04d}" for i in range(1000)])
+    keys = _codes(store, names)
+    scan = _sip_scan(store, _keys_filter(keys))
+    rows = _drain(scan)
+    n = _range_rows(store, scan)
+    assert n == (2400 if sparse else 3000)
+    assert _seeks(len(keys), n) == sparse
+    assert scan.stats.extra.get("sip_key_seeks", 0) == (1 if sparse else 0)
+    assert set(rows[0].tolist()) == set(keys.tolist())
+    assert len(rows[0]) == 3 * len(keys)
+    if sparse:
+        assert scan.stats.rows_scanned == 3 * len(keys)
+    else:
+        assert scan.stats.rows_scanned == n
+        assert scan._mask_filters[0].words is not None  # bloom-masked
+
+
+def test_scan_sip_seek_rule_boundary():
+    """Every subject of a span (3 rows a key): just under the rule's
+    break-even the scan seeks, just over it it keeps range + bloom."""
+    store = _seek_store()
+    # k keys, 3k rows: seek iff k * (SEEK_ROWS_PER_KEY - 3) < SEEK_FIXED_ROWS
+    edge = -(-scan_mod.SEEK_FIXED_ROWS // (scan_mod.SEEK_ROWS_PER_KEY - 3))  # first k that ranges
+    assert edge <= 1000
+    for k, seeks in ((edge - 1, 1), (edge, 0)):
+        keys = _codes(store, [f":s{i:04d}" for i in range(k)])
+        scan = _sip_scan(store, _keys_filter(keys))
+        assert len(_drain(scan)[0]) == 3 * k
+        n = _range_rows(store, scan)
+        assert n == 3 * k
+        assert _seeks(k, n) == bool(seeks)
+        assert scan.stats.extra.get("sip_key_seeks", 0) == seeks
+
+
+def test_scan_sip_key_seek_skip_and_reset():
+    """skip() on a seeking scan moves its cursor to the first matching row
+    whose key is >= the target, never backwards; reset() drops the seek
+    state, and the next read seeks afresh."""
+    store = _seek_store()
+    names = [f":s{i:04d}" for i in range(0, 1000, 50)]  # 20 keys, 60 rows
+    keys = _codes(store, names)
+    scan = IndexScan(store, P_SO, want_sorted_var=0, sip_filters=[_keys_filter(keys)],
+                     sizer=AdaptiveBatchSizer(initial=32, enabled=False))
+    b = scan.next_batch()
+    first = b.columns[b.col_index(0), : b.n_rows].tolist()
+    b.release()
+    assert len(first) == 32 and first[0] == keys[0]
+    assert scan.stats.extra["sip_key_seeks"] == 1
+    scan.skip(0, int(store.dict.lookup(":s0720")))  # between keys 14 and 15
+    scan.skip(0, int(keys[3]))  # behind the cursor: no move
+    b = scan.next_batch()
+    got = b.columns[b.col_index(0), : b.n_rows]
+    b.release()
+    np.testing.assert_array_equal(got, np.repeat(keys[15:], 3))
+    assert scan.next_batch() is None
+    assert scan.stats.rows_scanned == 32 + 15
+    scan.reset()
+    # past the last key before the first read: no row is left in the
+    # code range, and the scan ends without seeking
+    scan.skip(0, int(keys[-1]) + 1)
+    assert scan.next_batch() is None
+    scan.reset()
+    got = _drain(scan)[0]
+    np.testing.assert_array_equal(got, np.repeat(keys, 3))
+    assert scan.stats.extra["sip_key_seeks"] == 2
+
+
+def test_scan_sip_skip_before_the_first_read_holds_in_seek_mode():
+    store = _seek_store()
+    keys = _codes(store, [":s0010", ":s0500", ":s0900"])
+    scan = _sip_scan(store, _keys_filter(keys))
+    scan.skip(0, int(store.dict.lookup(":s0400")))
+    assert sorted(set(_drain(scan)[0].tolist())) == sorted(keys[1:].tolist())
+    assert scan.stats.extra["sip_key_seeks"] == 1
+
+
+@pytest.mark.parametrize("case", ["duplicates", "outside", "empty", "none_in_range"])
+def test_scan_sip_key_seek_edge_key_sets(case):
+    store = _seek_store()
+    if case == "duplicates":
+        keys = _codes(store, [":s0300", ":s0300", ":s0700", ":s0300", ":s0700"])
+        want = 6
+    elif case == "outside":
+        # terms that are no subject of :p widen the code range but own no
+        # run: only the one real key's rows come back
+        keys = np.concatenate([_codes(store, [":o3", ":s0555", ":o7"]),
+                               np.asarray([store.dict.lookup(":p") + 10**6], np.int32)])
+        want = 3
+    elif case == "none_in_range":
+        keys = np.asarray([10**7, 10**7 + 5], np.int32)  # codes past every row
+        want = 0
+    else:
+        keys = np.zeros(0, np.int32)
+        want = 0
+    f = _keys_filter(keys)
+    scan = _sip_scan(store, f)
+    got = _drain(scan)
+    assert len(got[0]) == want
+    assert set(got[0].tolist()) <= set(keys.tolist())
+    assert scan.stats.rows_scanned == want
+    assert f.words is None  # nothing was bloom-masked
+    if case == "duplicates":
+        assert len(f.keys) == 2 and list(f.keys) == sorted(f.keys)
+
+
+def test_scan_sip_two_filters_one_seeks_one_masks():
+    """A filter on the sorted var seeks; one on the other var masks the
+    rows the seek hands it."""
+    store = _seek_store()
+    keys = _codes(store, [":s0020", ":s0021", ":s0600", ":s0950"])
+    objs = _codes(store, [":o0", ":o1", ":o2"])
+    seek_f = _keys_filter(keys, var=0)
+    mask_f = _keys_filter(objs, var=1)
+    scan = _sip_scan(store, seek_f, mask_f)
+    got = _drain(scan)
+    assert scan.stats.extra["sip_key_seeks"] == 1
+    assert scan.stats.rows_scanned == 12
+    assert mask_f.rows_tested == 12 and mask_f.words is not None
+    assert seek_f.rows_tested == 0 and seek_f.words is None
+    want_s, want_o = [], []
+    for k in keys:
+        for j in range(3):
+            i = int(store.dict.decode(int(k))[2:])
+            o = store.dict.lookup(f":o{(i + j) % 11}")
+            if o in objs:
+                want_s.append(int(k))
+                want_o.append(o)
+    assert sorted(zip(got[0].tolist(), got[1].tolist())) == sorted(zip(want_s, want_o))
+
+
+def test_scan_sip_two_sorted_var_filters_seek_their_common_keys():
+    store = _seek_store()
+    a = _codes(store, [":s0100", ":s0200", ":s0300", ":s0400"])
+    b = _codes(store, [":s0200", ":s0400", ":s0401", ":s0402", ":s0900"])
+    fa, fb = _keys_filter(a), _keys_filter(b)
+    scan = _sip_scan(store, fa, fb)
+    got = _drain(scan)
+    assert sorted(set(got[0].tolist())) == sorted(_codes(store, [":s0200", ":s0400"]).tolist())
+    assert scan.stats.rows_scanned == 6
+    assert fa.words is None and fb.words is None
+
+
+def test_sip_filter_only_sought_never_builds_bloom():
+    store = _seek_store()
+    f = _keys_filter(_codes(store, [":s0001", ":s0999"]))
+    before = ops.dispatch_count("bloom_build")
+    _drain(_sip_scan(store, f))
+    assert ops.dispatch_count("bloom_build") == before
+    assert f.words is None and f.keys is not None
+    # the first mask() builds the words
+    assert f.mask(_codes(store, [":s0001", ":s0002"]))[0]
+    assert ops.dispatch_count("bloom_build") == before + 1 and f.words is not None
+
+
+def test_scan_sip_counters_charge_the_active_trace():
+    from repro.core import telemetry
+
+    store = _seek_store()
+    keys = _codes(store, [":s0005", ":s0995"])
+    with telemetry.trace_query("t") as tr:
+        _drain(_sip_scan(store, _keys_filter(keys)))
+        _drain(IndexScan(store, P_SO))  # no SIP: not counted
+    assert (tr.sip_scan_rows, tr.sip_seeks) == (6, 1)
+    assert tr.summary()["sip_scan_rows"] == 6
+    counter = [e for e in tr.chrome_events() if e["ph"] == "C"]
+    assert counter[0]["args"] == {"sip_scan_rows": 6, "sip_seeks": 1}
 
 
 # ---------------------------------------------------------------------------
