@@ -16,9 +16,12 @@ differs).
 from __future__ import annotations
 
 import collections
+import pathlib
 from typing import Dict, List, Sequence, Tuple
 
 Row = Tuple
+
+CONTROLS = pathlib.Path(__file__).resolve().parents[1] / "controls"
 
 
 def canonical(value):
@@ -78,23 +81,18 @@ def compare(got: Sequence[Row], want: Sequence[Row], spec: dict) -> bool:
 def control_answers(control: dict, reference, queries: Dict[str, dict],
                     requests) -> List[Tuple[str, List[Row]]]:
     """The control's answers to ``requests``: the plain reference put in
-    the program's place with one guarantee of the configuration broken,
-    as the mix's ``control`` names it. ``stale_answers``: each request
-    answered with the reference's answer to the previous request of its
-    query (the first with the last's), as a result cache keyed on the
-    query's template would."""
-    if control["kind"] != "stale_answers":
+    the program's place with one guarantee of the configuration broken.
+    The mix's ``control`` names its ``kind``, found by name as
+    ``bench/controls/<kind>.py``, whose ``answers(reference, queries,
+    requests, **params)`` gives them; the control's other keys are its
+    params."""
+    from bench.harness.runner import load_module  # runner imports this module
+
+    params = dict(control)
+    path = CONTROLS / f"{params.pop('kind')}.py"
+    if not path.is_file():
         raise ValueError(f"unknown control {control['kind']!r}")
-    last: Dict[str, int] = {}
-    for i, r in enumerate(requests):
-        last[r.query] = i
-    out = []
-    for i, r in enumerate(requests):
-        j = last[r.query]
-        last[r.query] = i
-        rows = reference.answer(r.query, requests[j].bind)
-        out.append((r.query, solution(rows, queries[r.query])))
-    return out
+    return load_module(path).answers(reference, queries, requests, **params)
 
 
 def judge(answers: Sequence[Tuple[str, Sequence[Row]]], reference, queries: Dict[str, dict],

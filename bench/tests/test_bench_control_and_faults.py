@@ -1,10 +1,11 @@
 """What decides ``correct`` fails what it must. The control (the plain
 reference put in the program's place with one guarantee broken) comes
-out not correct in every cell, and so does a whole run with the timed
-path broken underneath: an answer left unchanged from the request
-before, half of each emitted batch left out, and an answer altered where
-the server produces it. (One chip: no exchange between chips to leave
-out.) The reference in the program's place comes out correct."""
+out not correct in every cell and in the probe, and so does a whole run
+with the timed path broken underneath: an answer left unchanged from the
+request before, half of each emitted batch left out, and an answer
+altered where the server produces it. (One chip: no exchange between
+chips to leave out.) The reference in the program's place comes out
+correct."""
 
 import itertools
 
@@ -15,22 +16,22 @@ from bench import control
 from bench.harness import check
 from bench.harness.runner import Cell, load_module
 from bench.harness.traffic import Traffic
-from bench.tests.tiny_bench import CELLS, SEED, make_root, off_chip, run_cell
+from bench.tests.tiny_bench import PER_CELL, SEED, off_chip, probe_root, run_cell
 
 
 @pytest.fixture(scope="module")
 def root(tmp_path_factory):
-    return make_root(tmp_path_factory.mktemp("tiny"))
+    return probe_root(tmp_path_factory.mktemp("tiny"))
 
 
-@pytest.mark.parametrize("workload", CELLS)
+@pytest.mark.parametrize("workload", PER_CELL)
 def test_control_is_not_correct(root, workload):
     got = control.readings(root, workload, SEED)
     assert got["correct"] is False
     assert got["check"]["wrong_answers"]["value"] > 0
 
 
-@pytest.mark.parametrize("workload", CELLS)
+@pytest.mark.parametrize("workload", PER_CELL)
 def test_reference_in_the_programs_place_is_correct(root, workload):
     cell = Cell(root, workload)
     ds = cell.generator.generate(cell.config["params"], SEED)
@@ -88,7 +89,7 @@ def altered(monkeypatch):
 
 
 @pytest.mark.parametrize("fault", [stale, half_batch, altered], ids=lambda f: f.__name__)
-@pytest.mark.parametrize("workload", CELLS)
+@pytest.mark.parametrize("workload", PER_CELL)
 def test_a_run_with_a_broken_timed_path_is_not_correct(root, workload, fault, monkeypatch,
                                                        capsys):
     with off_chip(monkeypatch):

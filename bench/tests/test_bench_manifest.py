@@ -2,7 +2,7 @@
 within their characters, the keys each entry may have, every file found
 by name, every per-layer metric reported by each cell it lists, and a
 cell, configuration, traffic mix and metric added by files and entries
-alone."""
+alone, and so a configuration whose mix binds no constants (the probe)."""
 
 import json
 import re
@@ -10,13 +10,20 @@ import re
 import pytest
 
 from bench.harness.runner import Cell
-from bench.tests.tiny_bench import CELLS, MANIFEST, REPO, SEED, make_root, off_chip, run_cell
+from bench.tests.tiny_bench import (CELLS, MANIFEST, PER_CELL, PROBE, REPO, SEED, add_probe,
+                                     copy_source, make_root, off_chip, probe_root, run_cell,
+                                     tiny_cuts)
 
 MANIFESTS = pytest.mark.parametrize("manifest", [MANIFEST], ids=["committed"])
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 PATH = re.compile(r"^[A-Za-z0-9_.\-/]{1,200}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+
+
+@pytest.fixture(scope="module")
+def root(tmp_path_factory):
+    return probe_root(tmp_path_factory.mktemp("tiny"))
 
 
 def line(text):
@@ -102,9 +109,9 @@ def test_roofline_shares_are_percentages():
             assert m["unit"] == "%" and m["better"] == "higher"
 
 
-@pytest.mark.parametrize("workload", CELLS)
-def test_each_cell_reports_setup_another_end_to_end_and_a_per_layer_metric(workload):
-    cell = Cell(REPO, workload)
+@pytest.mark.parametrize("workload", PER_CELL)
+def test_each_cell_reports_setup_another_end_to_end_and_a_per_layer_metric(root, workload):
+    cell = Cell(root, workload)
     e2e = [m["name"] for m in cell.metrics(traced=False)]
     assert "setup_s" in e2e and len(e2e) >= 2
     assert cell.metrics(traced=True)
@@ -123,9 +130,9 @@ def test_metrics_of_one_layer_name_it_alike():
                       "kernels", "device"}
 
 
-@pytest.mark.parametrize("workload", CELLS)
-def test_every_file_of_a_cell_is_found_by_name(workload):
-    cell = Cell(REPO, workload)
+@pytest.mark.parametrize("workload", PER_CELL)
+def test_every_file_of_a_cell_is_found_by_name(root, workload):
+    cell = Cell(root, workload)
     assert cell.reference_path.exists()
     for m in cell.metrics(False) + cell.metrics(True):
         assert callable(cell.reader(m["name"]))
@@ -182,3 +189,29 @@ def test_a_cell_configuration_mix_and_metric_are_added_by_files_alone(tmp_path, 
         traced = run_cell(root, "bsbm-alt.lookups", capsys, trace=1, seed=SEED)
     assert plain["correct"] and set(plain["metrics"]) == {"qmph", "setup_s"}
     assert traced["correct"] and traced["metrics"]["rows_per_request.lookups"]["value"] > 1
+
+
+def test_a_configuration_whose_mix_binds_no_constants_is_added_by_files_alone(tmp_path):
+    """The probe: its configuration, generator, queries, reference, mix and
+    tiny cut as new files, its cell and metrics as new manifest entries; no
+    existing file of the benchmark written."""
+    source = copy_source(tmp_path / "source")
+    before = {p: p.read_bytes() for p in (source / "bench").rglob("*") if p.is_file()}
+    add_probe(source)
+    assert all(p.read_bytes() == b for p, b in before.items())
+    added = {p.relative_to(source / "bench").as_posix()
+             for p in (source / "bench").rglob("*") if p.is_file() and p not in before}
+    assert added == {"configs/probe-graph.json", "generators/probe_graph.py",
+                     "queries/probe.json", "references/probe.py", "traffic/probe-counts.json",
+                     "tests/tiny/probe-graph.json"}
+    cell = Cell(make_root(tmp_path / "root", source), PROBE)
+    assert cell.mix["bind"] == {} and cell.mix["control"]["kind"] == "approximate_numbers"
+    assert tiny_cuts(source)["probe-graph"].items() <= cell.config["params"].items()
+
+
+def test_a_configuration_without_a_tiny_cut_is_refused(tmp_path):
+    source = add_probe(copy_source(tmp_path / "source"))
+    (source / "bench" / "tests" / "tiny" / "probe-graph.json").unlink()
+    with pytest.raises(ValueError, match="probe-graph"):
+        make_root(tmp_path / "root", source)
+    assert not (tmp_path / "root").exists()

@@ -6,18 +6,18 @@ compile cache are skipped; no number here is a device's."""
 import pytest
 
 from bench.harness.runner import Cell
-from bench.tests.tiny_bench import CELLS, make_root, off_chip, run_cell
+from bench.tests.tiny_bench import PER_CELL, off_chip, probe_root, run_cell
 
 DEVICE_READ = ("device_ms", "dispatch_host_ms", "idle_pct", "roofline_pct")
 
 
 @pytest.fixture(scope="module")
 def root(tmp_path_factory):
-    return make_root(tmp_path_factory.mktemp("tiny"))
+    return probe_root(tmp_path_factory.mktemp("tiny"))
 
 
 @pytest.mark.parametrize("trace", [0, 1])
-@pytest.mark.parametrize("workload", CELLS)
+@pytest.mark.parametrize("workload", PER_CELL)
 def test_cell_runs_end_to_end_on_interpreted_kernels(root, workload, trace, monkeypatch,
                                                      capsys):
     # a window long enough for an interpreted request to complete on a

@@ -1,6 +1,7 @@
 """Each cell's store and traffic are made from the seed alone: the same
-seed gives the same triples and requests, another seed others, and seeds
-larger than 32 bits work. Requests follow the mix's order, draw each
+seed gives the same triples and requests, another seed another store
+and, where the mix binds constants, other requests, and seeds larger
+than 32 bits work. Requests follow the mix's order, draw each
 constant uniformly from its pool, and the store keeps BSBM's counts per
 class and per product."""
 
@@ -12,12 +13,12 @@ import pytest
 
 from bench.harness.runner import Cell
 from bench.harness.traffic import Traffic
-from bench.tests.tiny_bench import CELLS, REPO, SEED, TINY, make_root
+from bench.tests.tiny_bench import PER_CELL, REPO, SEED, TINY, probe_root
 
 
 @pytest.fixture(scope="module")
 def root(tmp_path_factory):
-    return make_root(tmp_path_factory.mktemp("tiny"))
+    return probe_root(tmp_path_factory.mktemp("tiny"))
 
 
 def traffic(root, workload, seed, n=40):
@@ -28,7 +29,7 @@ def traffic(root, workload, seed, n=40):
     return ds, t.warmup(), window
 
 
-@pytest.mark.parametrize("workload", CELLS)
+@pytest.mark.parametrize("workload", PER_CELL)
 def test_same_seed_same_store_and_requests(root, workload):
     ds1, warm1, win1 = traffic(root, workload, SEED)
     ds2, warm2, win2 = traffic(root, workload, SEED)
@@ -37,12 +38,14 @@ def test_same_seed_same_store_and_requests(root, workload):
     assert [r.text for r in win1] == [r.text for r in win2]
 
 
-@pytest.mark.parametrize("workload", CELLS)
+@pytest.mark.parametrize("workload", PER_CELL)
 def test_another_seed_another_store(root, workload):
     ds1, _, win1 = traffic(root, workload, SEED)
     ds2, _, win2 = traffic(root, workload, SEED + 1)
     assert not np.array_equal(ds1.spo, ds2.spo)
-    assert [r.text for r in win1] != [r.text for r in win2]
+    # the seed draws constants only where the mix binds some
+    texts_differ = [r.text for r in win1] != [r.text for r in win2]
+    assert texts_differ == bool(Cell(root, workload).mix["bind"])
 
 
 @pytest.mark.parametrize("seed", [0, 2**31 - 1, 2**32 + 5, 2**40 + 3])
