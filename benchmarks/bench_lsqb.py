@@ -34,22 +34,6 @@ def run(scale: float = 0.05, runs: int = 3, profile: bool = False) -> str:
         f"throughput_ratio={total_legacy / max(total_barq, 1e-9):.2f}x (paper: 3.4x)",
     )
 
-    # beyond-paper fused whole-BGP path on the motivating query (q6):
-    # compile once, then measure the steady-state fused count
-    import time
-
-    from repro.core.fused import fused_q6_count
-
-    fused_q6_count(store)  # compile + warm
-    t0 = time.perf_counter()
-    for _ in range(runs):
-        n = fused_q6_count(store)
-    dt = (time.perf_counter() - t0) / runs
-    op_time = time_query(store, LSQB_QUERIES["q6"], "barq", runs=runs)["mean_s"]
-    suite.add(
-        "lsqb_q6_barq_fused", dt * 1e6,
-        f"count={n};speedup_vs_operator_barq={op_time / max(dt, 1e-9):.1f}x",
-    )
     if profile:
         from repro.core import Engine, EngineConfig
 

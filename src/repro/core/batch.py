@@ -42,6 +42,38 @@ BATCH_BUCKETS: Tuple[int, ...] = tuple(
 )
 
 
+# Weighted counting (DESIGN.md §17): a COUNT(*) plan carries each row's
+# multiplicity. A fold's int64 weight w rides as two int32 columns, the
+# pair (w mod 2^31, w >> 31), under var ids WEIGHT_VAR + 2k and + 2k + 1,
+# so every operator carries it as it carries any column. A row's weight is
+# the product of its pairs; a row without pairs, or whose pair is unbound
+# (OPTIONAL with no match, the other branch of a UNION), weighs 1.
+WEIGHT_VAR = 1 << 30
+_LOW_BITS = (1 << 31) - 1
+
+
+def is_weight_var(v: int) -> bool:
+    return v >= WEIGHT_VAR
+
+
+def row_weights(var_ids: Sequence[int], cols: np.ndarray) -> Optional[np.ndarray]:
+    """Each row's int64 weight of an (n_vars, n) column block, or None
+    where the block carries no weight pair (every row weighs 1)."""
+    w = None
+    for i, v in enumerate(var_ids):
+        if v >= WEIGHT_VAR and (v - WEIGHT_VAR) % 2 == 0:
+            lo = cols[i].astype(np.int64)
+            hi = cols[list(var_ids).index(v + 1)].astype(np.int64)
+            f = np.where(lo == NULL_ID, 1, lo + (hi << 31))
+            w = f if w is None else w * f
+    return w
+
+
+def weight_pair(w: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+    """int64 weights (1 <= w < 2^62) as their two int32 columns."""
+    return (w & _LOW_BITS).astype(np.int32), (w >> 31).astype(np.int32)
+
+
 def bucket_for(n: int) -> int:
     """Smallest capacity bucket holding ``n`` rows."""
     for b in BATCH_BUCKETS:
@@ -265,6 +297,15 @@ class ColumnBatch:
 
     def active_column(self, var: int) -> np.ndarray:
         return self.column(var)[self.mask[: self.n_rows]]
+
+    def weight_total(self, n_active: int) -> int:
+        """Σ of the active rows' weights: ``n_active``, their number, where
+        the batch carries no weight pair."""
+        if max(self.var_ids, default=-1) < WEIGHT_VAR:
+            return n_active
+        self._guard()
+        w = row_weights(self.var_ids, self.columns[:, : self.n_rows])
+        return int(w[self.mask[: self.n_rows]].sum())
 
     # -- transforms ----------------------------------------------------------
 

@@ -353,7 +353,8 @@ class Translator:
             return SortDistinct(bchild, self.cfg.max_batch)
         if isinstance(n, PL.PGroup):
             child = self._build(n.child)
-            if mixed:
+            # weighted grouping (DESIGN.md §17) has no row operator
+            if mixed and not PL.is_weighted_group(n):
                 return LOP.RowGroupBy(
                     self._to_row(child), n.group_vars, n.aggs, self.store.dict
                 )
@@ -363,7 +364,7 @@ class Translator:
                 if gv is None or bchild.sorted_by() == gv:
                     return StreamingGroupBy(
                         bchild, gv, n.aggs, self.store.dict,
-                        self.cfg.max_batch, pool=self.pool,
+                        self.cfg.max_batch, pool=self.pool, weight=n.weight,
                     )
             if n.grace and n.group_vars:
                 return PartitionedGroupBy(
@@ -371,11 +372,11 @@ class Translator:
                     self.cfg.max_batch, pool=self.pool,
                     memory_budget=self.cfg.memory_budget,
                     spill_dir=self.cfg.spill_dir,
-                    n_parts=n.grace_parts or 16,
+                    n_parts=n.grace_parts or 16, weight=n.weight,
                 )
             return SortGroupBy(
                 bchild, n.group_vars, n.aggs, self.store.dict,
-                self.cfg.max_batch, pool=self.pool,
+                self.cfg.max_batch, pool=self.pool, weight=n.weight,
             )
         if isinstance(n, PL.PHaving):
             # HAVING: expression-VM filter over the aggregate output

@@ -33,6 +33,13 @@ int64 composite key, assigns dense group ids, and streams the sorted runs
 through StreamingGroupBy — sort-based grouping, the TPU-idiomatic
 replacement for vectorized hash grouping (DESIGN.md §2).
 
+Weighted counting (DESIGN.md §17) runs on these same operators. Where
+the input carries row weights (``batch.WEIGHT_VAR`` pairs) or the group
+emits one (a fold), grouping sums each group's int64 weights exactly on
+the host instead of counting rows: COUNT(*) is that sum, and a fold
+emits it as the weight pair of the merged row. All aggregates of such a
+group are COUNT(*) (the planner's rule).
+
 StreamingDistinct implements DISTINCT-via-skip() for sorted inputs: after
 seeing key k it *skips* the child to k+1, scrolling over duplicates in
 storage (paper: 'highly efficient for queries with many duplicates').
@@ -48,7 +55,15 @@ import numpy as np
 
 from repro.core import vecops
 from repro.core.algebra import AggSpec
-from repro.core.batch import MAX_BATCH, NULL_ID, BatchPool, ColumnBatch
+from repro.core.batch import (
+    MAX_BATCH,
+    NULL_ID,
+    BatchPool,
+    ColumnBatch,
+    is_weight_var,
+    row_weights,
+    weight_pair,
+)
 from repro.core.dictionary import Dictionary
 from repro.core.operators.base import BatchOperator
 from repro.core.operators.sort import MaterializedSource, materialize
@@ -103,9 +118,14 @@ class _Carry:
     dcodes: Optional[Dict[int, List[np.ndarray]]] = None  # per-agg code chunks
 
 
+def _group_name(weight: Sequence[int]) -> str:
+    return "Fold" if weight else "Group"
+
+
 class StreamingGroupBy(BatchOperator):
     """GROUP BY <one var> with aggregates over input sorted by that var.
-    group_var None => global aggregation (single group)."""
+    group_var None => global aggregation (single group). ``weight``: the
+    fold's output weight pair (DESIGN.md §17)."""
 
     def __init__(
         self,
@@ -116,6 +136,7 @@ class StreamingGroupBy(BatchOperator):
         batch_size: int = MAX_BATCH,
         pool: Optional[BatchPool] = None,
         backend: Optional[str] = None,
+        weight: Sequence[int] = (),
     ):
         if group_var is not None:
             assert child.sorted_by() == group_var, "input must be sorted by group var"
@@ -126,6 +147,14 @@ class StreamingGroupBy(BatchOperator):
         self.batch_size = batch_size
         self.pool = pool
         self.backend = backend
+        self.weight = tuple(weight)
+        self.weighted = bool(self.weight) or any(
+            is_weight_var(v) for v in child.var_ids()
+        )
+        assert not self.weighted or all(a.var is None for a in self.aggs), (
+            "a weighted group computes COUNT(*) alone"
+        )
+        self._out_w: List[np.ndarray] = []  # weighted: int64 sum per group
         self._needs = [_agg_needs(a) for a in self.aggs]
         self._dset_aggs = tuple(
             ai for ai, need in enumerate(self._needs)
@@ -144,13 +173,13 @@ class StreamingGroupBy(BatchOperator):
         self._dd_ms = 0.0
         self._runs = 0
         super().__init__(
-            "Group",
+            _group_name(self.weight),
             f"by=?v{group_var} " + ",".join(f"{a.func}->?v{a.out}" for a in aggs),
         )
 
     def var_ids(self) -> Tuple[int, ...]:
         base = (self.g,) if self.g is not None else ()
-        return base + tuple(a.out for a in self.aggs)
+        return base + tuple(a.out for a in self.aggs) + self.weight
 
     def sorted_by(self) -> Optional[int]:
         return self.g
@@ -173,6 +202,7 @@ class StreamingGroupBy(BatchOperator):
     # -- aggregation -------------------------------------------------------------
 
     def _consume_all(self) -> None:
+        rows = 0
         while True:
             b = self.child.next_batch()
             if b is None:
@@ -186,8 +216,22 @@ class StreamingGroupBy(BatchOperator):
                 if self.g is not None
                 else np.zeros(cb.n_rows, dtype=np.int32)
             )
-            self._consume_batch(keys, cb)
+            rows += cb.n_rows
+            if self.weighted:
+                self._consume_weighted(keys, cb)
+            else:
+                self._consume_batch(keys, cb)
             cb.release()  # per-run partials copied into outputs / carry
+        if self.weighted:
+            if self.g is None and not self._out_keys and self.aggs:
+                # a global COUNT(*) over empty input is one row of 0; a
+                # fold of an empty input is no row
+                self._out_keys.append(np.zeros(1, dtype=np.int32))
+                self._out_w.append(np.zeros(1, dtype=np.int64))
+            self.stats.extra["fold_rows_in"] = rows
+            self.stats.extra["fold_rows_out"] = sum(map(len, self._out_keys))
+            self._drained = True
+            return
         self._close_carry()
         if self.g is None and not self._out_keys:
             # global aggregate over empty input still yields one row
@@ -295,6 +339,20 @@ class StreamingGroupBy(BatchOperator):
             for ai in self._dset_aggs
         }
         return stats, dinfo
+
+    def _consume_weighted(self, keys: np.ndarray, cb: ColumnBatch) -> None:
+        """Each run's exact int64 Σ of row weights (its length where no row
+        carries one). A run that continues the previous batch's last group
+        adds into it: the input is sorted by the key."""
+        run_keys, starts, lengths = vecops.run_boundaries(keys)
+        w = row_weights(cb.var_ids, cb.columns[:, : cb.n_rows])
+        sums = lengths.astype(np.int64) if w is None else np.add.reduceat(w, starts)
+        if self._out_keys and self._out_keys[-1][-1] == run_keys[0]:
+            self._out_w[-1][-1] += sums[0]
+            run_keys, sums = run_keys[1:], sums[1:]
+        if len(run_keys):
+            self._out_keys.append(run_keys.copy())
+            self._out_w.append(sums)
 
     def _consume_batch(self, keys: np.ndarray, cb: ColumnBatch) -> None:
         run_keys, starts, lengths = vecops.run_boundaries(keys)
@@ -431,20 +489,45 @@ class StreamingGroupBy(BatchOperator):
             codes[ok] = ids[inv]
         return codes
 
-    def _next(self) -> Optional[ColumnBatch]:
+    def _encode_sums(self, sums: np.ndarray) -> List[np.ndarray]:
+        """Weighted output columns: each COUNT(*) as its exact encoded
+        integer, then the fold's weight pair."""
+        cols: List[np.ndarray] = []
+        if self.aggs:
+            uniq, inv = np.unique(sums, return_inverse=True)
+            ids = np.asarray([self.dictionary.encode(int(u)) for u in uniq], dtype=np.int32)
+            cols = [ids[inv]] * len(self.aggs)
+        return cols + (list(weight_pair(sums)) if self.weight else [])
+
+    def _encoded_keys(self) -> np.ndarray:
+        """The output's group keys, the input drained and the output
+        encoded on the first call."""
         if not self._drained:
             self._consume_all()
         if self._enc_keys is None:
             self._enc_keys = (
                 np.concatenate(self._out_keys) if self._out_keys else _EMPTY_I32
             )
-            self._enc_cols = [
-                self._encode(
-                    np.concatenate(v) if v else np.zeros(0, dtype=np.float64)
+            if self.weighted:
+                self._enc_cols = self._encode_sums(
+                    np.concatenate(self._out_w) if self._out_w
+                    else np.zeros(0, dtype=np.int64)
                 )
-                for v in self._out_vals
-            ]
-        n = len(self._enc_keys)
+            else:
+                self._enc_cols = [
+                    self._encode(
+                        np.concatenate(v) if v else np.zeros(0, dtype=np.float64)
+                    )
+                    for v in self._out_vals
+                ]
+        return self._enc_keys
+
+    def _skip(self, var: int, target: int) -> None:
+        keys = self._encoded_keys()
+        self._emitted += int(np.searchsorted(keys[self._emitted:], target))
+
+    def _next(self) -> Optional[ColumnBatch]:
+        n = len(self._encoded_keys())
         if self._emitted >= n:
             return None
         hi = min(self._emitted + self.batch_size, n)
@@ -457,6 +540,7 @@ class StreamingGroupBy(BatchOperator):
     def _reset(self) -> None:
         self.child.reset()
         self._out_keys = []
+        self._out_w = []
         self._out_vals = [[] for _ in self.aggs]
         self._carry = _Carry()
         self._enc_keys = None
@@ -479,7 +563,8 @@ class SortGroupBy(BatchOperator):
     """General GROUP BY (multi-var or unsorted input): drain only the
     needed columns from pooled batches, sort ONCE by a packed int64
     composite key (vecops.pack_group_keys), assign dense group ids, and
-    stream the sorted runs through StreamingGroupBy."""
+    stream the sorted runs through StreamingGroupBy. The output is in
+    the group keys' lexicographic order, so sorted by the first."""
 
     def __init__(
         self,
@@ -490,6 +575,7 @@ class SortGroupBy(BatchOperator):
         batch_size: int = MAX_BATCH,
         pool: Optional[BatchPool] = None,
         backend: Optional[str] = None,
+        weight: Sequence[int] = (),
     ):
         self.child = child
         self.group_vars = tuple(group_vars)
@@ -498,12 +584,16 @@ class SortGroupBy(BatchOperator):
         self.batch_size = batch_size
         self.pool = pool
         self.backend = backend
+        self.weight = tuple(weight)
         self._src: Optional[BatchOperator] = None
         self._stream: Optional[StreamingGroupBy] = None
-        super().__init__("Group", f"by={self.group_vars} (sort-based)")
+        super().__init__(_group_name(self.weight), f"by={self.group_vars} (sort-based)")
 
     def var_ids(self) -> Tuple[int, ...]:
-        return self.group_vars + tuple(a.out for a in self.aggs)
+        return self.group_vars + tuple(a.out for a in self.aggs) + self.weight
+
+    def sorted_by(self) -> Optional[int]:
+        return self.group_vars[0] if self.group_vars else None
 
     def children(self) -> List[BatchOperator]:
         return [self.child]
@@ -526,9 +616,11 @@ class SortGroupBy(BatchOperator):
         return np.zeros((len(need), 0), dtype=np.int32)
 
     def _need_vars(self) -> Tuple[Tuple[int, ...], Tuple[int, ...]]:
+        """(grouping + aggregate input columns, the aggregate inputs); the
+        input's row weights count as aggregate inputs."""
         avars = tuple(
             dict.fromkeys(a.var for a in self.aggs if a.var is not None)
-        )
+        ) + tuple(v for v in self.child.var_ids() if is_weight_var(v))
         return tuple(dict.fromkeys(self.group_vars + avars)), avars
 
     def _aggregate_block(
@@ -567,7 +659,7 @@ class SortGroupBy(BatchOperator):
         )
         self._stream = StreamingGroupBy(
             inner_src, _GID, self.aggs, self.dictionary, self.batch_size,
-            backend=self.backend,
+            backend=self.backend, weight=self.weight,
         )
         # drain the stream (small: one row per group), then translate the
         # dense gid back to the group-key column values via each group's
@@ -576,7 +668,7 @@ class SortGroupBy(BatchOperator):
         gids = scols[0]
         first_row = starts[gids] if n else np.zeros(0, dtype=np.int64)
         out_cols = [kr[first_row] for kr in key_rows]
-        out_cols.extend(scols[1 + ai] for ai in range(len(self.aggs)))
+        out_cols.extend(scols[1:])
         for k, v in self._stream.stats.extra.items():
             if k.endswith("_ms") or isinstance(v, (int, float)):
                 self.stats.extra[k] = self.stats.extra.get(k, 0) + v
@@ -595,13 +687,16 @@ class SortGroupBy(BatchOperator):
         cols = self._drain_needed(need)
         block = self._aggregate_block(cols, need, avars)
         self._src = MaterializedSource(
-            self.var_ids(), block, None, self.batch_size, name="GroupOut",
-            pool=self.pool,
+            self.var_ids(), block, self.sorted_by(), self.batch_size,
+            name="GroupOut", pool=self.pool,
         )
         return self._src
 
     def _next(self) -> Optional[ColumnBatch]:
         return self._ensure().next_batch()
+
+    def _skip(self, var: int, target: int) -> None:
+        self._ensure().skip(var, target)
 
     def _reset(self) -> None:
         self.child.reset()
@@ -701,7 +796,9 @@ class PartitionedGroupBy(SortGroupBy):
     Each group's rows land in exactly one partition (same key tuple ->
     same partition id), so per-partition outputs concatenate into the
     global result — the whole input is never sorted or resident at once,
-    unlike the parent's single-argsort path."""
+    unlike the parent's single-argsort path. A group with no aggregate
+    (a fold) sorts its output by its keys at the end, as the planner
+    claims it is."""
 
     def __init__(
         self,
@@ -715,17 +812,21 @@ class PartitionedGroupBy(SortGroupBy):
         memory_budget: Optional[int] = None,
         spill_dir: Optional[str] = None,
         n_parts: int = 16,
+        weight: Sequence[int] = (),
     ):
         assert group_vars, "partitioned grouping needs group keys"
         super().__init__(
-            child, group_vars, aggs, dictionary, batch_size, pool, backend
+            child, group_vars, aggs, dictionary, batch_size, pool, backend,
+            weight,
         )
         self.memory_budget = memory_budget
         self.spill_dir = spill_dir
         self.n_parts = max(2, n_parts)
         self._rel: Optional[PartitionedRelation] = None
-        self.stats.name = "Group"
         self.stats.detail = f"by={self.group_vars} (partitioned)"
+
+    def sorted_by(self) -> Optional[int]:
+        return None if self.aggs else self.group_vars[0]
 
     def _partition_input(self, need: Tuple[int, ...]) -> PartitionedRelation:
         rel = PartitionedRelation(
@@ -759,12 +860,15 @@ class PartitionedGroupBy(SortGroupBy):
             if blocks
             else np.zeros((len(self.var_ids()), 0), dtype=np.int32)
         )
+        if not self.aggs and block.shape[1]:
+            keys = block[: len(self.group_vars)]
+            block = block[:, np.argsort(vecops.pack_group_keys(keys), kind="stable")]
         self.stats.extra["grace_partitions"] = self.n_parts
         self.stats.extra["spill_bytes"] = self._rel.spill_bytes
         self.stats.extra["spill_files"] = self._rel.spill_files
         self._src = MaterializedSource(
-            self.var_ids(), block, None, self.batch_size, name="GroupOut",
-            pool=self.pool,
+            self.var_ids(), block, self.sorted_by(), self.batch_size,
+            name="GroupOut", pool=self.pool,
         )
         return self._src
 

@@ -13,6 +13,7 @@ import time
 from typing import List, Optional, Tuple
 
 from repro.core import batch as _batch
+from repro.core import telemetry
 from repro.core.batch import ColumnBatch
 
 
@@ -61,6 +62,10 @@ class OpStats:
 class BatchOperator:
     """Base class: pull-based batch iteration with skip support."""
 
+    # join operators set this: the rows they emit, and Σ of those rows'
+    # weights, are charged to the request's trace (``count_join_rows``)
+    emits_join_rows = False
+
     def __init__(self, name: str, detail: str = "") -> None:
         self.stats = OpStats(name, detail)
 
@@ -82,8 +87,11 @@ class BatchOperator:
             if san is not None:
                 san.pop_op()
         if b is not None:
+            n = b.n_active
             self.stats.batches += 1
-            self.stats.results += b.n_active
+            self.stats.results += n
+            if self.emits_join_rows and n:
+                telemetry.count_join_rows(b, n)
         return b
 
     def skip(self, var: int, target: int) -> None:

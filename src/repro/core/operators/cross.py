@@ -15,6 +15,8 @@ from repro.core.operators.sort import materialize
 
 
 class CrossJoin(BatchOperator):
+    emits_join_rows = True
+
     def __init__(
         self,
         left: BatchOperator,

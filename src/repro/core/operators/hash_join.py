@@ -78,6 +78,8 @@ def _n_parts_for(n_build: int) -> int:
 
 
 class HashJoin(BatchOperator):
+    emits_join_rows = True
+
     def __init__(
         self,
         probe: BatchOperator,

@@ -27,6 +27,8 @@ from repro.kernels import ops as KOPS
 
 
 class LookupJoin(BatchOperator):
+    emits_join_rows = True
+
     def __init__(
         self,
         probe: BatchOperator,

@@ -200,6 +200,8 @@ class _Window:
 
 
 class MergeJoin(BatchOperator):
+    emits_join_rows = True
+
     def __init__(
         self,
         left: BatchOperator,

@@ -21,6 +21,7 @@ BARQ).
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import hashlib
 import math
@@ -28,6 +29,7 @@ from typing import Dict, FrozenSet, List, Optional, Sequence, Tuple, Union as TU
 
 from repro.core import algebra as A
 from repro.core import telemetry
+from repro.core.batch import WEIGHT_VAR, is_weight_var
 from repro.core.stats import GraphStats
 
 # ---------------------------------------------------------------------------
@@ -198,6 +200,14 @@ class PGroup(PhysNode):
     # budget-directed partitioned grouping (DESIGN.md §15)
     grace: bool = dataclasses.field(default=False, compare=False)
     grace_parts: int = dataclasses.field(default=0, compare=False)
+    # weighted counting's fold (DESIGN.md §17): no aggregates, the group's
+    # Σ of row weights as this weight pair (var ids from WEIGHT_VAR)
+    weight: Tuple[int, ...] = ()
+
+
+def is_weighted_group(n: PGroup) -> bool:
+    """A fold, or a COUNT(*) over rows that carry weights (DESIGN.md §17)."""
+    return bool(n.weight) or any(is_weight_var(v) for v in phys_vars(n.child))
 
 
 @dataclasses.dataclass
@@ -266,7 +276,7 @@ def phys_vars(n: Phys) -> Tuple[int, ...]:
     if isinstance(n, (PCross, PUnion)):
         return tuple(dict.fromkeys(phys_vars(n.left) + phys_vars(n.right)))
     if isinstance(n, PGroup):
-        return n.group_vars + tuple(a.out for a in n.aggs)
+        return n.group_vars + tuple(a.out for a in n.aggs) + n.weight
     if isinstance(n, POrderBy):
         return phys_vars(n.child)
     raise TypeError(type(n))
@@ -316,7 +326,8 @@ def phys_sorted_by(n: Phys) -> Optional[int]:
             phys_vars(n.child)[0] if len(phys_vars(n.child)) == 1 else None
         )
     if isinstance(n, PGroup):
-        return n.group_vars[0] if n.streaming and n.group_vars else None
+        # a group with no aggregate (a fold) emits its keys in order
+        return n.group_vars[0] if (n.streaming or not n.aggs) and n.group_vars else None
     return None
 
 
@@ -486,6 +497,57 @@ def annotate_fingerprints(n: Phys, canon: Dict[int, int]) -> None:
 # ---------------------------------------------------------------------------
 
 
+# the nodes a row weight crosses (DESIGN.md §17)
+_WEIGHABLE = (A.BGP, A.Filter, A.Join, A.LeftJoin, A.Minus, A.NotExists, A.Union, A.Extend)
+
+
+def _count_sites(node: A.PlanNode) -> Optional[collections.Counter]:
+    """Weighted counting's rule (DESIGN.md §17). Where the query's one
+    aggregate computes COUNT(*) alone, no DISTINCT lies anywhere and only
+    joins, filters, OPTIONAL, MINUS, NOT EXISTS, UNION and BIND lie below
+    it: for each variable, the places it occurs (a triple pattern, an
+    expression, the group keys, a projection, an ORDER BY). Else None."""
+    sites: collections.Counter = collections.Counter()
+    aggs = []
+
+    def expr(e) -> None:
+        if e is not None:
+            sites.update(set(A.expr_vars(e)))
+
+    def walk(n, below: bool) -> bool:
+        if isinstance(n, A.BGP):
+            for p in n.patterns:
+                sites.update(set(p.vars()))
+            return True
+        if below and not isinstance(n, _WEIGHABLE):
+            return False
+        if isinstance(n, A.GroupAgg):
+            aggs.append(n)
+            sites.update(set(n.group_vars))
+            expr(n.having)
+            return walk(n.child, True)
+        if isinstance(n, A.Distinct):
+            return False
+        if isinstance(n, (A.Filter, A.LeftJoin)):
+            expr(n.expr)
+        elif isinstance(n, A.Extend):
+            sites.update({n.var})
+            expr(n.expr)
+        elif isinstance(n, A.Project):
+            sites.update(set(n.vars))
+        elif isinstance(n, A.OrderBy):
+            sites.update({k.var for k in n.keys})
+        return all(walk(c, below) for c in (
+            getattr(n, f, None) for f in ("child", "left", "right")
+        ) if isinstance(c, A.PlanNode))
+
+    if not walk(node, False) or len(aggs) != 1:
+        return None
+    if not all(a.func == "count" and a.var is None and not a.distinct for a in aggs[0].aggs):
+        return None
+    return sites
+
+
 # hash-join cost constants (DESIGN.md §11 strategy table): building the
 # partitioned layout touches every build row a few times (partition, reorder,
 # probe bookkeeping), a sort costs ~ n log2 n row moves. The constants only
@@ -550,11 +612,19 @@ class Planner:
             getattr(stats, "store", None), "dict", None
         )
         self._prog_cache: dict = {}
+        # weighted counting (DESIGN.md §17) in the batch engines; tests turn
+        # it off to compare against the unweighted plan
+        self.weighted = barq_enabled
+        # the planned query's variable sites where weighted counting applies
+        self._sites: Optional[collections.Counter] = None
+        self._n_folds = 0
 
     # -- public -------------------------------------------------------------------
 
     def plan(self, node: A.PlanNode) -> Phys:
         self._canon = telemetry.canonical_var_map(node)
+        self._sites = _count_sites(node) if self.weighted else None
+        self._n_folds = 0
         phys = self._plan(node)
         if self.sip != "off":
             self._sip_walk(phys)
@@ -857,7 +927,11 @@ class Planner:
         if isinstance(node, A.GroupAgg):
             child = self._plan(node.child)
             gv = tuple(node.group_vars)
-            streaming = (len(gv) == 1 and phys_sorted_by(child) == gv[0]) or len(gv) == 0
+            if self._sites is not None:
+                # weighted counting (DESIGN.md §17): the inputs below fold
+                # to what they share, and COUNT(*) sums the row weights
+                child = self._weigh_children(child, frozenset(gv), None)
+            streaming = self._streams(child, gv)
             # resort to enable streaming aggregation when cheap (§3.3)
             if len(gv) == 1 and not streaming:
                 child = PSort(child, gv[0])
@@ -886,6 +960,85 @@ class Planner:
             )
             return out
         raise TypeError(f"cannot plan {type(node)}")
+
+    @staticmethod
+    def _streams(child: Phys, gv: Sequence[int]) -> bool:
+        """Grouping by ``gv`` streams: no key, or one the input is sorted by."""
+        return len(gv) == 0 or (len(gv) == 1 and phys_sorted_by(child) == gv[0])
+
+    # -- weighted counting (DESIGN.md §17) ---------------------------------------------
+
+    def _fold(self, n: Phys, keep: Sequence[int], est: float) -> PGroup:
+        """A fold: grouping by ``keep`` with no aggregate; its weight pair
+        is set where the plan is final (``_weigh_children``)."""
+        out = PGroup(n, tuple(keep), (), self._streams(n, keep))
+        out.est_rows = max(1.0, min(n.est_rows, est))
+        return out
+
+    def _weigh(self, n: Phys, need: FrozenSet[int], first: Optional[int]) -> Phys:
+        """``n`` carrying weights, folded to the vars of ``need`` it
+        produces wherever it produces any other. A fold sorts by ``first``
+        where it keeps it, else by what ``n`` is sorted by."""
+        n = self._weigh_children(n, need, first)
+        outs = [v for v in phys_vars(n) if not is_weight_var(v)]
+        if all(v in need for v in outs):
+            return n
+        lead = first if first is not None else phys_sorted_by(n)
+        keep = [v for v in outs if v in need and v != lead]
+        if lead in need and lead in outs:
+            keep.insert(0, lead)
+        out = self._fold(n, keep, math.prod(self._distinct_estimate(n, v) for v in keep))
+        out.weight = self._weight_ids()
+        return out
+
+    def _weight_ids(self) -> Tuple[int, int]:
+        k = WEIGHT_VAR + 2 * self._n_folds
+        self._n_folds += 1
+        return k, k + 1
+
+    def _weigh_children(
+        self, n: Phys, need: FrozenSet[int], first: Optional[int]
+    ) -> Phys:
+        """``n`` with each input folded to what ``n`` and everything above
+        it read: ``need``, join keys (every shared var), conditions.
+        Rewrites ``n`` in place; a Sort whose input a fold already sorts
+        goes."""
+        if isinstance(n, (PScan, PPathExpand)):
+            return n
+        if isinstance(n, PGroup):  # a fold
+            if not n.weight:  # placed by the join order search
+                n.weight = self._weight_ids()
+                n.child = self._weigh_children(n.child, frozenset(n.group_vars), None)
+                n.streaming = self._streams(n.child, n.group_vars)
+            return n
+        if isinstance(n, PFilter):
+            n.child = self._weigh(n.child, need | set(A.expr_vars(n.expr)), first)
+            return n
+        if isinstance(n, PExtend):
+            n.child = self._weigh(
+                n.child, (need - {n.var}) | set(A.expr_vars(n.expr)), first
+            )
+            return n
+        if isinstance(n, PSort):
+            n.child = self._weigh(n.child, need | {n.var}, n.var)
+            return n.child if phys_sorted_by(n.child) == n.var else n
+        if isinstance(n, PUnion):
+            n.left = self._weigh(n.left, need, None)
+            n.right = self._weigh(n.right, need, None)
+            return n
+        sides = ("left", "right") if isinstance(n, (PMergeJoin, PCross)) else (
+            "probe", "build")
+        a, b = (getattr(n, f) for f in sides)
+        shared = set(phys_vars(a)) & set(phys_vars(b))
+        cond = getattr(n, "post_filter", None)
+        shared |= set(A.expr_vars(cond)) if cond is not None else set()
+        mode = getattr(n, "mode", "inner")
+        jv = n.var if isinstance(n, PMergeJoin) else None
+        setattr(n, sides[0], self._weigh(a, need | shared, jv))
+        setattr(n, sides[1], self._weigh(
+            b, need | shared if mode in ("inner", "left_outer") else shared, jv
+        ))
+        return n
 
     # -- BGP join ordering (greedy System-R style) ---------------------------------------
 
@@ -925,8 +1078,10 @@ class Planner:
         return self.stats.distinct_values(p, var)
 
     # beyond this many patterns the exact DP's subset enumeration (3^n)
-    # would dominate planning time; fall back to the greedy loop
-    _BUSHY_MAX = 8
+    # would dominate planning time; fall back to the greedy loop. A cyclic
+    # BGP of 9 patterns (LSQB q3) plans in ~0.2 s, one of 10 in ~0.4 s, on
+    # one host core
+    _BUSHY_MAX = 10
 
     def _plan_bgp(self, patterns: Sequence[A.TriplePattern], filters: List[A.Expr]) -> Phys:
         assert patterns
@@ -944,7 +1099,10 @@ class Planner:
         SIP-aware probe discounts, so shapes like (A⋈B)⋈(C⋈D) — which the
         greedy linear loop can never emit — win when two small
         intermediate results exist. Returns None for disconnected BGPs
-        (the greedy loop's cartesian handling covers those)."""
+        (the greedy loop's cartesian handling covers those). Under weighted
+        counting each subset's plan is folded to the vars the other
+        patterns and the rest of the query read, and costed at that size
+        (eager aggregation)."""
         n = len(pats)
         leaves: List[Phys] = []
         for p in pats:
@@ -957,8 +1115,10 @@ class Planner:
         for m in range(1, 1 << n):
             low = m & -m
             vmask[m] = vmask[m ^ low] | vsets[low.bit_length() - 1]
+        full = (1 << n) - 1
+        fold = self._subset_fold(pats, vsets, vmask, full)
         # best[mask] = (cost, plan)
-        best: dict = {1 << i: (leaves[i].est_rows, leaves[i]) for i in range(n)}
+        best: dict = {1 << i: (leaves[i].est_rows, fold(1 << i, leaves[i])) for i in range(n)}
         for m in sorted(range(1, 1 << n), key=lambda x: bin(x).count("1")):
             if bin(m).count("1") < 2:
                 continue
@@ -973,13 +1133,36 @@ class Planner:
                     join, jc = self._join_subplans(pa, pb)
                     tot = ca + cb + jc
                     if m not in best or tot < best[m][0]:
-                        best[m] = (tot, join)
+                        best[m] = (tot, fold(m, join))
                 sub = (sub - 1) & m
-        full = (1 << n) - 1
         if full not in best:
             return None
         plan = best[full][1]
         return self._attach_filters(plan, filters)
+
+    def _subset_fold(self, pats: List, vsets, vmask, full: int):
+        """``fold(mask, plan)``: under weighted counting, the plan of a
+        proper subset of ``pats`` folded to the vars that the other
+        patterns or the rest of the query read, wherever it holds another;
+        else the plan itself."""
+        if self._sites is None:
+            return lambda m, plan: plan
+        inside = collections.Counter(v for vs in vsets for v in vs)
+        outside = {v for v in inside if self._sites[v] > inside[v]}
+
+        def fold(m: int, plan: Phys) -> Phys:
+            keep = [v for v in phys_vars(plan)
+                    if v in outside or v in vmask[full ^ m]]
+            if m == full or len(keep) == len(phys_vars(plan)):
+                return plan
+            est = math.prod(
+                min(self._pattern_distinct(pats[i], v) for i in range(len(pats))
+                    if m >> i & 1 and v in vsets[i])
+                for v in keep
+            )
+            return self._fold(plan, keep, est)
+
+        return fold
 
     def _join_subplans(self, left: Phys, right: Phys) -> Tuple[Phys, float]:
         """Join two DP subplans: pick the join var (preferring an already
@@ -1167,6 +1350,8 @@ class Planner:
     def _distinct_estimate(self, n: Phys, var: int) -> int:
         if isinstance(n, PScan):
             return self.stats.distinct_values(n.pattern, var)
+        if self._sites is not None and isinstance(n, PGroup) and n.group_vars == (var,):
+            return max(int(n.est_rows), 1)  # a fold's rows are its keys
         return max(int(n.est_rows ** 0.5), 1)
 
     def _leaf(self, p, sort_var: Optional[int] = None) -> Phys:
@@ -1453,7 +1638,10 @@ def explain(n: Phys, var_table: Optional[A.VarTable] = None, indent: int = 0) ->
             kind = f"partitioned parts={n.grace_parts}"
         else:
             kind = "streaming" if n.streaming else "sort"
-        return f"{pad}Group[{kind}]\n" + explain(n.child, var_table, indent + 1)
+        fold = ""
+        if n.weight:
+            fold = f" fold({', '.join(vname(v) for v in n.group_vars)}) {estf(n)}"
+        return f"{pad}Group[{kind}]{fold}\n" + explain(n.child, var_table, indent + 1)
     if isinstance(n, POrderBy):
         return f"{pad}OrderBy\n" + explain(n.child, var_table, indent + 1)
     if isinstance(n, PSlice):

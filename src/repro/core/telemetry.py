@@ -13,8 +13,10 @@ buffer to exactly one request:
                    on each QueryTrace.
   QueryTrace     — span recorder for the query lifecycle (parse → plan →
                    translate → execute), a per-query KernelLedger, a
-                   per-dispatch kernel event log, and the rows and key
-                   seeks of SIP-consuming scans (``count_sip_scan``).
+                   per-dispatch kernel event log, the rows and key
+                   seeks of SIP-consuming scans (``count_sip_scan``), and
+                   the rows join operators emitted with their summed
+                   weights (``count_join_rows``).
                    Exports Chrome-trace
                    JSON (``chrome-tracing`` / Perfetto ``traceEvents``
                    format) so traces open directly in ui.perfetto.dev.
@@ -326,6 +328,16 @@ def count_sip_scan(rows: int, seeks: int = 0) -> None:
         tr.sip_seeks += seeks
 
 
+def count_join_rows(batch, rows: int) -> None:
+    """Charge the ``rows`` active rows of a batch a join operator emitted,
+    and the Σ of their weights (the solutions they stand for under weighted
+    counting, DESIGN.md §17), to the active request's trace."""
+    tr = _ACTIVE_TRACE.get()
+    if tr is not None:
+        tr.join_rows += rows
+        tr.join_weight += batch.weight_total(rows)
+
+
 def record_compile(program: str, seconds: float) -> None:
     """Charge one compile to the dispatch in flight (with its inputs'
     shapes), else to the active request, and always to the process-global
@@ -392,6 +404,10 @@ class QueryTrace:
         # that sought the build keys' runs (``count_sip_scan``)
         self.sip_scan_rows = 0
         self.sip_seeks = 0
+        # rows join operators emitted, and Σ of their weights
+        # (``count_join_rows``)
+        self.join_rows = 0
+        self.join_weight = 0
         # (label, depth, start_s, dur_s, args) — synthesized operator lane
         self._operators: List[Tuple[str, float, float, dict]] = []
 
@@ -493,6 +509,9 @@ class QueryTrace:
         ev.append({"name": "sip_scan", "ph": "C", "ts": self._us(end), "pid": 1,
                    "args": {"sip_scan_rows": self.sip_scan_rows,
                             "sip_seeks": self.sip_seeks}})
+        ev.append({"name": "join", "ph": "C", "ts": self._us(end), "pid": 1,
+                   "args": {"join_rows": self.join_rows,
+                            "join_weight": self.join_weight}})
         for label, t0, dur, args in self._operators:
             ev.append(
                 {
@@ -561,6 +580,8 @@ class QueryTrace:
             "d2h_bytes": sum(d.d2h_bytes for d in self.dispatches),
             "sip_scan_rows": self.sip_scan_rows,
             "sip_seeks": self.sip_seeks,
+            "join_rows": self.join_rows,
+            "join_weight": self.join_weight,
         }
 
 

@@ -1,12 +1,11 @@
 """End-to-end behaviour tests for the paper's system: full query pipeline
 (parse → optimize → translate → execute → decode) on the paper's own
-workload shapes, engine co-existence, and the fused beyond-paper path."""
+workload shapes and engine co-existence."""
 
 import numpy as np
 import pytest
 
 from repro.core import Engine, EngineConfig
-from repro.core.fused import fused_q6_count
 from repro.core.profiler import collect_stats
 from repro.data import (
     BSBM_BI_QUERIES,
@@ -42,11 +41,6 @@ def test_lsqb_queries_all_engines_agree(social, qname):
     # CPU-bound suite should actually produce work
     if qname in ("q1", "q6", "q9"):
         assert counts["barq"] > 0
-
-
-def test_motivating_example_matches_fused(social):
-    store, _ = social
-    assert _count(store, LSQB_QUERIES["q6"], "barq") == fused_q6_count(store)
 
 
 @pytest.mark.parametrize("tname", sorted(BSBM_EXPLORE_TEMPLATES))
